@@ -80,7 +80,7 @@ def partition_kernel_rows(n: int = 1 << 20, d: int = 2, k: int = 64):
     from repro.launch.kernel_roofline import PLATFORMS, predict
     rows = []
     for platform in PLATFORMS:
-        backend = "jnp" if platform == "cpu_host" else "pallas"
+        backend = "jnp" if platform == "cpu" else "pallas"
         for prune in (0.0, 0.5):
             p = predict(n, d, k, platform=platform, backend=backend,
                         prune_frac=prune)

@@ -40,12 +40,14 @@ ALL = ["quality", "scaling", "repartition", "serving", "experiments",
        "components", "moe_router", "roofline"]
 
 
-def _force_virtual_devices() -> None:
+def _prepare_env() -> None:
     """Expose 8 virtual CPU devices so the SPMD scaling section runs on
-    single-CPU hosts. Must run before the first jax import — main() calls
-    this before importing any benchmark module."""
-    from repro.envflags import force_virtual_devices
+    single-CPU hosts, and turn on the persistent compilation cache. Must
+    run before the first jax import — main() calls this before importing
+    any benchmark module."""
+    from repro.envflags import force_virtual_devices, use_compile_cache
     force_virtual_devices(8)
+    use_compile_cache()
 
 
 def main() -> None:
@@ -60,7 +62,7 @@ def main() -> None:
                          "repartition, serving, experiments)")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else ALL
-    _force_virtual_devices()
+    _prepare_env()
 
     failures = []
     for name in names:

@@ -200,9 +200,12 @@ def weak_scaling_memory(quick: bool = False) -> dict:
     and 2-D mesh labels bit-identical to the flat composition.
     """
     n = WEAK_MEM_N_QUICK if quick else WEAK_MEM_N
+    # the probe measures host RSS on virtual CPU devices; pinning it to
+    # the CPU keeps it off an accelerator this (parent) process holds
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count="
-                         f"{WEAK_MEM_DEVICES}")
+                         f"{WEAK_MEM_DEVICES}",
+               JAX_PLATFORMS="cpu")
     env.setdefault("PYTHONPATH", "src")
     cmd = [sys.executable, "-m", "benchmarks.scaling", "--memprobe",
            str(n), str(WEAK_MEM_K), str(WEAK_MEM_DEVICES),

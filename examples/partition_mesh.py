@@ -21,6 +21,10 @@ all 8 blocks into 8 sub-blocks each in ONE batched vmap dispatch; block b
 owns labels [8b, 8b+8) and the measured global imbalance still respects
 ``epsilon``.
 """
+from repro.envflags import use_compile_cache
+
+use_compile_cache()          # before the first jax import
+
 import argparse
 import time
 

@@ -4,6 +4,10 @@ bisection, then track a drifting load with a warm-started repartition.
 
     PYTHONPATH=src python examples/quickstart.py [--quick]
 """
+from repro.envflags import use_compile_cache
+
+use_compile_cache()          # before the first jax import
+
 import argparse
 
 import numpy as np

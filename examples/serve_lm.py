@@ -8,6 +8,10 @@ dry-run cells at production scale.
 
     PYTHONPATH=src python examples/serve_lm.py [--arch rwkv6_3b]
 """
+from repro.envflags import use_compile_cache
+
+use_compile_cache()          # before the first jax import
+
 import argparse
 import time
 

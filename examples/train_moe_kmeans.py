@@ -17,6 +17,10 @@ Presets:
 test (exercises the full train loop but skips the learning assertion,
 which needs a few hundred steps to hold).
 """
+from repro.envflags import use_compile_cache
+
+use_compile_cache()          # before the first jax import
+
 import argparse
 
 import jax

@@ -208,7 +208,8 @@ def make_distributed_partitioner(mesh, cfg: BKMConfig, axis_name="data"):
     [N] (aligned with the *redistributed* order), plus diagnostics.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+
+    from repro.dist.rules import shard_map
 
     n_shards = mesh.shape[axis_name]
 
@@ -230,8 +231,7 @@ def make_distributed_partitioner(mesh, cfg: BKMConfig, axis_name="data"):
         local_fn, mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name)),
         out_specs=(P(axis_name, None), P(axis_name, None, None),
-                   P(axis_name, None), P(), P(), P(), P()),
-        check_rep=False)
+                   P(axis_name, None), P(), P(), P(), P()))
 
     @jax.jit
     def run(points, weights):

@@ -92,6 +92,19 @@ def partition_mesh2d(p1: int, p2: int) -> Mesh:
                 (COARSE_AXIS, REFINE_AXIS))
 
 
+def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` as every SPMD body of this repository uses it.
+
+    Replication checking is off (``check_vma=False``, the current name of
+    the former ``check_rep=False``): centers, influence and metric totals
+    are replicated by construction (they are psum results, or identical
+    inputs), which the checker cannot always prove through the k-means
+    while-loops and the assign kernels.
+    """
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def _batch_axes(mesh: Mesh):
     if _POD in mesh.axis_names:
         return (_POD, _DATA)

@@ -153,10 +153,9 @@ def _build_metrics_fn(devices: int, cap: int, ecap: int, n: int, k: int):
     with every output replicated (already psum'd inside)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.rules import PARTITION_AXIS, partition_mesh
+    from repro.dist.rules import PARTITION_AXIS, partition_mesh, shard_map
 
     mesh = partition_mesh(devices)
     axis = PARTITION_AXIS
@@ -195,8 +194,7 @@ def _build_metrics_fn(devices: int, cap: int, ecap: int, n: int, k: int):
     inner = shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(), P()),
-        check_rep=False)
+        out_specs=(P(), P(), P()))
     return jax.jit(inner)
 
 

@@ -32,17 +32,12 @@ TPU adaptation of the paper's geometric optimizations (DESIGN.md §4):
   adds its one-hot-matmul partial after its last center tile, so both grid
   dimensions become ``arbitrary`` (sequential) to keep the accumulation
   well-defined.
-* ``double_buffer=True`` (the roofline-driven DMA optimization,
-  DESIGN.md §4c): the point array moves to ``ANY`` (compiler-placed,
-  HBM-resident) memory and the kernel DMAs point tiles into a two-slot
-  VMEM scratch itself — tile ``i+1``'s copy is started when tile ``i``
-  begins its center sweep, so the HBM fetch of the next point tile
-  overlaps the MXU work of the current one across the whole center-tile
-  loop instead of only the one-block lookahead of the automatic
-  pipeline. Cross-iteration DMA state forces both grid dimensions
-  sequential (``arbitrary``); the default (``None``) enables it only for
-  the compiled TPU path and keeps the interpreter on the automatically
-  pipelined variant (CI covers both via an explicit flag).
+* Every per-point vector (weights in, idx/best/second out) is a ``[1, N]``
+  row with ``(1, block_p)`` blocks, and the per-tile prune bounds are
+  read as SMEM scalars. Both keep every block legal for Mosaic's
+  (8, 128) tiling rule, also under ``vmap`` (the batched hierarchical
+  refinement), where a 1-D ``(block_p,)`` block would become an illegal
+  ``(1, block_p)`` slice of a ``[B, N]`` array.
 * ``precision="bf16"`` computes the ``p @ c^T`` cross term on the MXU in
   bf16 (f32 accumulation); the norms ``|p|^2``/``|c|^2``, the Hamerly
   best/second accumulators and the moment block stay f32. Tolerance
@@ -50,9 +45,9 @@ TPU adaptation of the paper's geometric optimizations (DESIGN.md §4):
 
 Grid: ``(n_point_tiles, n_center_tiles)``. VMEM per step: BP*D + BC*D +
 BP*BC floats (+ 3 BP-sized accumulators, + BP + (d+2)*K + BP*K in moments
-mode, + 2*BP*D double-buffer scratch) — e.g. BP=1024, BC=128, D<=128,
-K=1024 → ~5.5 MB, under the ~16 MB v5e VMEM budget, with BP*BC = 1024x128
-matching MXU tiling (multiples of 128 on the lane dimension).
+mode) — e.g. BP=1024, BC=128, D<=128, K=1024 → ~5.5 MB, under the
+~16 MB v5e VMEM budget, with BP*BC = 1024x128 matching MXU tiling
+(multiples of 128 on the lane dimension).
 """
 from __future__ import annotations
 
@@ -62,11 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_ANY = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_ANY = _ANY.ANY
 
 PRECISIONS = ("f32", "bf16")
 
@@ -91,14 +81,20 @@ def _check_tiling(n: int, k: int, block_p: int, block_c: int,
 def _cross_term(p, c, precision: str):
     """-2 p @ c^T cross term of the squared distance, [BP, BC] f32.
 
-    ``bf16`` casts both operands to bfloat16 before the MXU matmul
-    (accumulation stays f32 via ``preferred_element_type``): half the
-    operand bandwidth and double the MXU rate on TPU, at a relative
-    distance error bounded by ~2^-8 per coordinate product."""
+    ``f32`` asks for full f32 contraction (``Precision.HIGHEST``): the
+    TPU's default f32 matmul rounds its operands to bfloat16, which the
+    f32 mode must not do. ``bf16`` casts both operands to bfloat16 before
+    the MXU matmul (accumulation stays f32 via ``preferred_element_type``):
+    half the operand bandwidth and double the MXU rate on TPU, at a
+    relative distance error bounded by ~2^-8 per coordinate product."""
     if precision == "bf16":
         p = p.astype(jnp.bfloat16)
         c = c.astype(jnp.bfloat16)
+        exact = None
+    else:
+        exact = jax.lax.Precision.HIGHEST
     return jax.lax.dot_general(p, c, (((1,), (1,)), ((), ())),
+                               precision=exact,
                                preferred_element_type=jnp.float32)
 
 
@@ -106,8 +102,7 @@ def _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
                  second_ref, *, block_c: int, k_real: int, precision: str):
     """One (point-tile × center-tile) grid step: init at the first center
     tile, tile-level bbox pruning, distance matmul + running
-    (best, second, argmin) update. ``p`` is the point tile, however it got
-    into VMEM (automatic pipeline or the double-buffer scratch)."""
+    (best, second, argmin) update in the ``[1, BP]`` output rows."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -118,7 +113,7 @@ def _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
 
     # Tile-level Hamerly/bbox pruning: skip this center tile when its
     # lower bound cannot improve any point's second-best.
-    bound = bounds_ref[0, 0]
+    bound = bounds_ref[0, j]
     worst_second = jnp.max(second_ref[...])
 
     @pl.when((j == 0) | (bound < worst_second))
@@ -141,18 +136,18 @@ def _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
         onehot = jax.nn.one_hot(local_idx, bc, dtype=jnp.bool_)
         local_second = jnp.min(jnp.where(onehot, jnp.inf, eff), axis=1)
 
-        old_best = best_ref[...]
-        old_second = second_ref[...]
-        old_idx = idx_ref[...]
+        old_best = best_ref[0]
+        old_second = second_ref[0]
+        old_idx = idx_ref[0]
         take_new = local_best < old_best
         new_best = jnp.where(take_new, local_best, old_best)
         new_second = jnp.minimum(
             jnp.minimum(old_second, local_second),
             jnp.maximum(old_best, local_best))
         new_idx = jnp.where(take_new, j * block_c + local_idx, old_idx)
-        best_ref[...] = new_best
-        second_ref[...] = new_second
-        idx_ref[...] = new_idx
+        best_ref[0] = new_best
+        second_ref[0] = new_second
+        idx_ref[0] = new_idx
 
 
 def _moments_step(p, w_ref, idx_ref, best_ref, moments_ref):
@@ -172,9 +167,9 @@ def _moments_step(p, w_ref, idx_ref, best_ref, moments_ref):
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _accumulate():
-        w = w_ref[...]                                       # [BP]
-        idx = idx_ref[...]                                   # [BP]
-        best = best_ref[...]                                 # [BP]
+        w = w_ref[0]                                         # [BP]
+        idx = idx_ref[0]                                     # [BP]
+        best = best_ref[0]                                   # [BP]
         kpad = moments_ref.shape[1]
         onehot = idx[:, None] == jax.lax.broadcasted_iota(
             jnp.int32, (p.shape[0], kpad), 1)                # [BP, K]
@@ -184,53 +179,14 @@ def _moments_step(p, w_ref, idx_ref, best_ref, moments_ref):
             axis=1)                                          # [BP, D+2]
         moments_ref[...] += jax.lax.dot_general(
             stacked, ww, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)              # [D+2, K]
-
-
-def _points_db(points_hbm, pbuf, sem, block_p: int):
-    """Double-buffered point-tile fetch: wait for tile ``i``'s DMA (slot
-    ``i % 2``) at its first center tile and immediately start tile
-    ``i+1``'s copy into the other slot, so the next tile's HBM read is in
-    flight for the whole center sweep of the current one. Returns the
-    current tile's VMEM view. Requires a sequential point-tile grid
-    dimension (cross-iteration scratch + semaphore state)."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    def dma(slot, tile):
-        return pltpu.make_async_copy(
-            points_hbm.at[pl.ds(tile * block_p, block_p), :],
-            pbuf.at[slot], sem.at[slot])
-
-    @pl.when((i == 0) & (j == 0))
-    def _warmup():
-        dma(0, 0).start()
-
-    @pl.when(j == 0)
-    def _rotate():
-        dma(i % 2, i).wait()
-
-        @pl.when(i + 1 < pl.num_programs(0))
-        def _prefetch():
-            dma((i + 1) % 2, i + 1).start()
-
-    return pbuf[i % 2]
 
 
 def _assign_kernel(bounds_ref, points_ref, centers_ref, inv2_ref,
                    idx_ref, best_ref, second_ref, *, block_c: int,
                    k_real: int, precision: str):
     _assign_step(points_ref[...], bounds_ref, centers_ref, inv2_ref,
-                 idx_ref, best_ref, second_ref, block_c=block_c,
-                 k_real=k_real, precision=precision)
-
-
-def _assign_kernel_db(bounds_ref, points_hbm, centers_ref, inv2_ref,
-                      idx_ref, best_ref, second_ref, pbuf, sem, *,
-                      block_p: int, block_c: int, k_real: int,
-                      precision: str):
-    p = _points_db(points_hbm, pbuf, sem, block_p)
-    _assign_step(p, bounds_ref, centers_ref, inv2_ref,
                  idx_ref, best_ref, second_ref, block_c=block_c,
                  k_real=k_real, precision=precision)
 
@@ -246,106 +202,77 @@ def _assign_moments_kernel(bounds_ref, points_ref, centers_ref, inv2_ref,
     _moments_step(p, w_ref, idx_ref, best_ref, moments_ref)
 
 
-def _assign_moments_kernel_db(bounds_ref, points_hbm, centers_ref,
-                              inv2_ref, w_ref, idx_ref, best_ref,
-                              second_ref, moments_ref, pbuf, sem, *,
-                              block_p: int, block_c: int, k_real: int,
-                              precision: str):
-    p = _points_db(points_hbm, pbuf, sem, block_p)
-    _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
-                 second_ref, block_c=block_c, k_real=k_real,
-                 precision=precision)
-    _moments_step(p, w_ref, idx_ref, best_ref, moments_ref)
-
-
 def default_interpret() -> bool:
     """Backend auto-detection: run the Mosaic-compiled kernel on real TPUs,
     the Pallas interpreter everywhere else (CPU CI containers, GPU hosts)."""
     return jax.default_backend() != "tpu"
 
 
-def _resolve_db(double_buffer: bool | None, interpret: bool) -> bool:
-    # auto: manual DMA overlap pays on real hardware; the interpreter
-    # emulates DMAs synchronously, so default to the pipelined variant
-    # there (tests opt in explicitly to cover the DMA path on CPU).
-    return (not interpret) if double_buffer is None else double_buffer
+def _specs(n: int, d: int, k: int, block_p: int, block_c: int):
+    """Grid and the blocks shared by both kernels: the point tile's row of
+    prune bounds in SMEM (``[N/BP, 1, K/BC]``, one ``(1, K/BC)`` block per
+    point tile, so SMEM use grows with K only), point/center tiles, the
+    ``[1, K]`` inverse-influence row, and the ``[1, N]`` per-point rows
+    (weights, idx, best, second)."""
+    grid = (n // block_p, k // block_c)
+    bounds = pl.BlockSpec((None, 1, grid[1]), lambda i, j: (i, 0, 0),
+                          memory_space=pltpu.SMEM)
+    points = pl.BlockSpec((block_p, d), lambda i, j: (i, 0))
+    centers = pl.BlockSpec((block_c, d), lambda i, j: (j, 0))
+    inv2 = pl.BlockSpec((1, block_c), lambda i, j: (0, j))
+    row = pl.BlockSpec((1, block_p), lambda i, j: (0, i))
+    return grid, [bounds, points, centers, inv2], row
+
+
+def _row_shapes(n: int):
+    return [jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32)]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k_real", "block_p", "block_c",
-                                    "interpret", "precision",
-                                    "double_buffer"))
+                                    "interpret", "precision"))
 def assign_argmin_pallas(points, centers, inv2, tile_bounds, k_real: int,
                          block_p: int = 1024, block_c: int = 128,
                          interpret: bool | None = None,
-                         precision: str = "f32",
-                         double_buffer: bool | None = None):
+                         precision: str = "f32"):
     """points [N, D], centers [K, D] (pre-padded), inv2 [K] = 1/influence^2,
     tile_bounds [N/BP, K/BC], k_real = number of real (non-_FAR) centers.
-    Returns (idx, best_eff_sq, second_eff_sq).
+    Returns (idx, best_eff_sq, second_eff_sq), each [N].
 
     ``interpret=None`` auto-detects: compiled on TPU, interpret elsewhere.
     Pass an explicit bool to override (e.g. interpret-mode debugging on
-    TPU hosts). ``precision`` is the distance-matmul mode ("f32"/"bf16");
-    ``double_buffer`` selects the manual two-slot point-tile DMA (None =
-    only when compiled)."""
+    TPU hosts). ``precision`` is the distance-matmul mode ("f32"/"bf16")."""
     if interpret is None:
         interpret = default_interpret()
     n, d = points.shape
     k = centers.shape[0]
     _check_tiling(n, k, block_p, block_c, "assign_argmin_pallas")
-    db = _resolve_db(double_buffer, interpret)
-    grid = (n // block_p, k // block_c)
-    common = [
-        pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),      # centers
-        pl.BlockSpec((1, block_c), lambda i, j: (0, j)),      # inv2
-    ]
-    if db:
-        kernel = functools.partial(_assign_kernel_db, block_p=block_p,
-                                   block_c=block_c, k_real=k_real,
-                                   precision=precision)
-        points_spec = pl.BlockSpec(memory_space=_ANY)
-        scratch = [pltpu.VMEM((2, block_p, d), jnp.float32),
-                   pltpu.SemaphoreType.DMA((2,))]
-        semantics = ("arbitrary", "arbitrary")
-    else:
-        kernel = functools.partial(_assign_kernel, block_c=block_c,
-                                   k_real=k_real, precision=precision)
-        points_spec = pl.BlockSpec((block_p, d), lambda i, j: (i, 0))
-        scratch = []
-        semantics = ("parallel", "arbitrary")
+    grid, in_specs, row = _specs(n, d, k, block_p, block_c)
+    kernel = functools.partial(_assign_kernel, block_c=block_c,
+                               k_real=k_real, precision=precision)
     idx, best, second = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, j)),  # bounds
-                  points_spec] + common,
-        out_specs=[
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        in_specs=in_specs,
+        out_specs=[row, row, row],
+        out_shape=_row_shapes(n),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tile_bounds, points, centers, inv2[None, :])
-    return idx, best, second
+    )(tile_bounds[:, None, :], points, centers, inv2[None, :])
+    return idx[0], best[0], second[0]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k_real", "block_p", "block_c",
-                                    "interpret", "precision",
-                                    "double_buffer"))
+                                    "interpret", "precision"))
 def assign_reduce_pallas(points, centers, inv2, tile_bounds, weights,
                          k_real: int, block_p: int = 1024,
                          block_c: int = 128,
                          interpret: bool | None = None,
-                         precision: str = "f32",
-                         double_buffer: bool | None = None):
+                         precision: str = "f32"):
     """Fused assign+reduce: one pass over the point tiles returning
     (idx, best_eff_sq, second_eff_sq, moments [d+2, K]) with the moment
     block accumulated in VMEM across point tiles (sorted-center columns:
@@ -357,47 +284,22 @@ def assign_reduce_pallas(points, centers, inv2, tile_bounds, weights,
     n, d = points.shape
     k = centers.shape[0]
     _check_tiling(n, k, block_p, block_c, "assign_reduce_pallas")
-    db = _resolve_db(double_buffer, interpret)
-    grid = (n // block_p, k // block_c)
-    if db:
-        kernel = functools.partial(_assign_moments_kernel_db,
-                                   block_p=block_p, block_c=block_c,
-                                   k_real=k_real, precision=precision)
-        points_spec = pl.BlockSpec(memory_space=_ANY)
-        scratch = [pltpu.VMEM((2, block_p, d), jnp.float32),
-                   pltpu.SemaphoreType.DMA((2,))]
-    else:
-        kernel = functools.partial(_assign_moments_kernel, block_c=block_c,
-                                   k_real=k_real, precision=precision)
-        points_spec = pl.BlockSpec((block_p, d), lambda i, j: (i, 0))
-        scratch = []
+    grid, in_specs, row = _specs(n, d, k, block_p, block_c)
+    kernel = functools.partial(_assign_moments_kernel, block_c=block_c,
+                               k_real=k_real, precision=precision)
     idx, best, second, moments = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),            # bounds
-            points_spec,                                          # points
-            pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),      # centers
-            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),      # inv2
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),          # weights
-        ],
-        out_specs=[
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-            pl.BlockSpec((block_p,), lambda i, j: (i,)),
-            pl.BlockSpec((d + 2, k), lambda i, j: (0, 0)),        # moments
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((d + 2, k), jnp.float32),
-        ],
-        scratch_shapes=scratch,
+        in_specs=in_specs + [row],                                # weights
+        out_specs=[row, row, row,
+                   pl.BlockSpec((d + 2, k), lambda i, j: (0, 0))],  # moments
+        out_shape=_row_shapes(n) + [
+            jax.ShapeDtypeStruct((d + 2, k), jnp.float32)],
         # the moment block accumulates across BOTH grid dimensions, so the
         # point-tile dimension must be sequential too
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_bounds, points, centers, inv2[None, :], weights)
-    return idx, best, second, moments
+    )(tile_bounds[:, None, :], points, centers, inv2[None, :],
+      weights[None, :])
+    return idx[0], best[0], second[0], moments

@@ -31,9 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 FAR = 1e30
 
 
@@ -115,7 +112,7 @@ def router_topk_pallas(x, centroids, inv2, top_k: int, bt: int = 256,
         ],
         # outputs are revisited running accumulators along the expert-tile
         # dimension -> it must be sequential; token tiles stay parallel
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, centroids, inv2[None, :])

@@ -35,18 +35,15 @@ Registered backends:
                fused moments fold into the same chunk loop.
 * ``pallas`` — the fused TPU kernel (assign_kernel.py): tile-level
                Hamerly/bbox pruning, centers pre-sorted by bbox distance,
-               moments accumulated in VMEM across point tiles,
-               double-buffered point-tile DMA when compiled.
+               moments accumulated in VMEM across point tiles.
 * ``triton`` — the GPU-portable variant (triton_assign.py): 1-D grid over
                point tiles, in-kernel loop over center tiles, split-k
                moment partials — no TPU-only primitives, so the same body
                is Mosaic-GPU/Triton lowerable; interpret-verified on CPU.
 * ``auto``   — per-platform resolution, in order: the
-               ``REPRO_ASSIGN_BACKEND`` env override; ``pallas`` whenever
-               ``REPRO_PALLAS_INTERPRET=1`` forces interpret mode (the CI
-               switch that exercises the kernel path on CPU); ``pallas``
-               on real TPUs (``jnp`` for sub-tile shard_map shards);
-               ``triton`` on GPUs; ``jnp`` on CPU.
+               ``REPRO_ASSIGN_BACKEND`` env override; ``pallas`` on real
+               TPUs (``jnp`` for sub-tile shard_map shards); ``triton``
+               on GPUs; ``jnp`` on CPU.
 
 All backends accept ``precision`` ("f32" default, "bf16" = bf16 distance
 matmul with f32 accumulation — DESIGN.md §4c documents the tolerance) and
@@ -59,9 +56,9 @@ hosts, costing ~1.35x at the gate shape n=2^20 k=64).
 Third-party backends can be added with ``@register_assign_backend(name)``
 (e.g. a CUDA Triton port); ``BKMConfig.backend`` then selects them by
 name. Pallas kernels themselves auto-detect compiled-vs-interpret from the
-jax backend (assign_kernel.default_interpret); set
-``REPRO_PALLAS_INTERPRET=0/1`` to force either mode, and
-``REPRO_ASSIGN_BACKEND=<name>`` to pin what ``auto`` resolves to.
+jax backend (assign_kernel.default_interpret): compiled on a TPU, the
+interpreter elsewhere. ``REPRO_ASSIGN_BACKEND=<name>`` pins what ``auto``
+resolves to (``pallas`` on a CPU runs the kernel in interpret mode).
 """
 from __future__ import annotations
 
@@ -74,13 +71,8 @@ import jax.numpy as jnp
 from .assign_kernel import (assign_argmin_pallas, assign_reduce_pallas,
                             default_interpret)
 
-_env = os.environ.get("REPRO_PALLAS_INTERPRET")
-_INTERPRET: bool | None = None if _env is None else _env != "0"
 _FAR = 1e30   # padded-center coordinate; masked out by k_real in-kernel
-
-
-def _interpret_mode() -> bool:
-    return default_interpret() if _INTERPRET is None else _INTERPRET
+_F32 = jax.lax.Precision.HIGHEST   # f32 matmuls stay f32 on a TPU
 
 
 def default_chunk(k: int) -> int:
@@ -134,14 +126,11 @@ def resolve_assign_backend(name: str = "auto", *, sharded: bool = False,
        ``auto`` is overridden: an explicitly named backend always wins,
        so suites that exercise a specific backend stay meaningful under
        the override.
-    2. forced interpret (``REPRO_PALLAS_INTERPRET=1``) → ``pallas`` —
-       the CI switch that exercises the kernel code path (including the
-       fused moment accumulators) on CPU-only runners.
-    3. real TPU → ``pallas`` (but ``jnp`` for sub-tile shard_map shards,
+    2. real TPU → ``pallas`` (but ``jnp`` for sub-tile shard_map shards,
        see below).
-    4. GPU → ``triton`` (the portable 1-D-grid kernel; no TPU-only
+    3. GPU → ``triton`` (the portable 1-D-grid kernel; no TPU-only
        primitives, Mosaic-GPU lowerable).
-    5. otherwise (CPU) → ``jnp``.
+    4. otherwise (CPU) → ``jnp``.
 
     Keyed off ``default_interpret()`` so the backend choice and the
     kernel's compiled-vs-interpret decision share one predicate.
@@ -163,8 +152,6 @@ def resolve_assign_backend(name: str = "auto", *, sharded: bool = False,
                     f"assign backend; available: "
                     f"{available_assign_backends()}")
             return env
-        if _INTERPRET:                 # forced interpret: cover the kernel
-            return "pallas"
         if not default_interpret():    # real TPU
             if sharded and n_local is not None and n_local < 1024:
                 return "jnp"
@@ -193,14 +180,16 @@ def _chunk_assign(p, cn, centers, inv2, precision: str = "f32"):
     (idx, best, second, onehot) — ``onehot`` [C, k] bool marks each
     point's winning center and is reused by the fused moment reduction.
     ``precision="bf16"`` casts only the cross-term matmul operands to
-    bfloat16 (f32 accumulation); norms and the epilogue stay f32."""
+    bfloat16 (f32 accumulation); norms and the epilogue stay f32. The f32
+    matmuls here ask for ``Precision.HIGHEST``: a TPU's default f32
+    matmul rounds its operands to bfloat16 (the CPU ignores the flag)."""
     pn = jnp.sum(p * p, axis=1, keepdims=True)
     if precision == "bf16":
         cross2 = 2.0 * jax.lax.dot_general(
             p.astype(jnp.bfloat16), centers.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     else:
-        cross2 = 2.0 * p @ centers.T    # == (2p) @ c.T, the legacy form
+        cross2 = jnp.matmul(2.0 * p, centers.T, precision=_F32)
     sq = pn + cn[None, :] - cross2
     eff = jnp.maximum(sq, 0.0) * inv2[None, :]
     k = eff.shape[1]
@@ -226,7 +215,10 @@ def _chunk_moments(onehot, p, w, best):
     ww = jnp.where(onehot, w[:, None], 0.0)                  # [C, k]
     stacked = jnp.concatenate(
         [p, jnp.ones((p.shape[0], 1), p.dtype), best[:, None]], axis=1)
-    return ww.T @ stacked                                    # [k, d+2]
+    # contracted in place (no explicit transpose): under vmap the result
+    # then does not depend on the batch size on XLA:CPU, which keeps the
+    # sharded batched refinement bit-identical to the host vmap
+    return jnp.einsum("ck,cd->kd", ww, stacked, precision=_F32)  # [k, d+2]
 
 
 def _split_moments(m, d):
@@ -346,20 +338,19 @@ def _tile_bounds(points, centers, inv2, block_p, block_c):
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "block_c",
-                                             "return_moments", "precision",
-                                             "double_buffer"))
+                                             "return_moments", "precision"))
 def assign_argmin(points, centers, influence, block_p: int = 1024,
                   block_c: int = 128, weights=None,
-                  return_moments: bool = False, precision: str = "f32",
-                  double_buffer: bool | None = None):
+                  return_moments: bool = False, precision: str = "f32"):
     """Drop-in replacement for ref.assign_argmin_ref (same returns).
 
     ``return_moments=True`` (requires ``weights``) runs the fused
     assign+reduce kernel: the per-cluster weighted moments are accumulated
     in VMEM across point tiles and un-sorted back to original center ids
     here, so the [n, d] point array is streamed exactly once.
-    ``precision``/``double_buffer`` pass through to the kernel (DESIGN.md
-    §4c): bf16 distance matmul and manual two-slot point-tile DMA.
+    ``precision`` passes through to the kernel (DESIGN.md §4c: bf16
+    distance matmul). The kernel runs compiled on a TPU and interpreted
+    elsewhere (``assign_kernel.default_interpret``).
     """
     n, d = points.shape
     k = centers.shape[0]
@@ -389,8 +380,7 @@ def assign_argmin(points, centers, influence, block_p: int = 1024,
         w = jnp.pad(weights, (0, pad_n)).astype(jnp.float32)
         idx_s, best, second, m = assign_reduce_pallas(
             pts, cts, iv2, bounds, w, k_real=k, block_p=block_p,
-            block_c=block_c, interpret=_interpret_mode(),
-            precision=precision, double_buffer=double_buffer)
+            block_c=block_c, precision=precision)
         # un-sort the [d+2, K_pad] moment block: sorted column j belongs
         # to original center order[j]; padded columns carry no weight
         m_orig = jnp.zeros((k, d + 2), jnp.float32).at[order].set(m.T[:k])
@@ -400,8 +390,7 @@ def assign_argmin(points, centers, influence, block_p: int = 1024,
                 m_orig[:, :d], m_orig[:, d], m_orig[:, d + 1])
     idx_s, best, second = assign_argmin_pallas(
         pts, cts, iv2, bounds, k_real=k, block_p=block_p, block_c=block_c,
-        interpret=_interpret_mode(), precision=precision,
-        double_buffer=double_buffer)
+        precision=precision)
     idx_s, best, second = idx_s[:n], best[:n], second[:n]
     # map sorted-center index back to the original center id
     idx = order[jnp.clip(idx_s, 0, k - 1)].astype(jnp.int32)
@@ -490,7 +479,7 @@ def flash_attention(q, k, v, bq: int = 512, bk: int = 512,
     kh = kt.transpose(0, 2, 1, 3).reshape(B * KV, Sp, dh)
     vh = vt.transpose(0, 2, 1, 3).reshape(B * KV, Sp, dh)
     o = flash_attention_pallas(qh, kh, vh, bq=bq, bk=bk, softcap=softcap,
-                               interpret=_interpret_mode())
+                               interpret=default_interpret())
     o = o.reshape(B, H, Sp, dh).transpose(0, 2, 1, 3)
     return o[:, :S]
 
@@ -514,7 +503,7 @@ def router_topk(x, centroids, influence, top_k: int, bt: int = 256,
     ip = jnp.pad(inv2, (0, pad_e), constant_values=1.0).astype(jnp.float32)
     idx, eff = router_topk_pallas(xp, cp, ip, top_k=top_k, bt=bt,
                                   block_e=block_e, e_real=E,
-                                  interpret=_interpret_mode())
+                                  interpret=default_interpret())
     return idx[:T], eff[:T]
 
 

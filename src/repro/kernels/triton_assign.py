@@ -39,9 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .assign_kernel import _check_tiling, _cross_term, default_interpret
 
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _sweep_centers(p, centers_ref, inv2_ref, *, block_c: int, k_real: int,
                    precision: str):
@@ -112,6 +109,7 @@ def _triton_moments_kernel(points_ref, centers_ref, inv2_ref, w_ref,
         [p, jnp.ones((p.shape[0], 1), p.dtype), best[:, None]], axis=1)
     partial_ref[...] = jax.lax.dot_general(
         stacked, ww, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)[None]            # [1, D+2, K]
 
 
@@ -147,7 +145,7 @@ def triton_assign_pallas(points, centers, inv2, k_real: int,
             jax.ShapeDtypeStruct((n,), jnp.float32),
             jax.ShapeDtypeStruct((n,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(points, centers, inv2[None, :])
 
@@ -189,7 +187,7 @@ def triton_assign_reduce_pallas(points, centers, inv2, weights,
             jax.ShapeDtypeStruct((n,), jnp.float32),
             jax.ShapeDtypeStruct((n_pt, d + 2, k), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(points, centers, inv2[None, :], weights)
     # split-k reduction of the per-program partials (deterministic XLA sum)
@@ -219,7 +217,6 @@ def triton_assign_backend(points, centers, influence, *,
     fast-memory use). Unlike the ``pallas`` backend there is no center
     sort, so indices and moments come out in original center order."""
     del chunk
-    from .ops import _interpret_mode
     n = points.shape[0]
     k = centers.shape[0]
     pts, cts, iv2 = _pad_inputs(points, centers, influence, block_p,
@@ -230,13 +227,13 @@ def triton_assign_backend(points, centers, influence, *,
         w = jnp.pad(weights, (0, pts.shape[0] - n)).astype(jnp.float32)
         idx, best, second, m = triton_assign_reduce_pallas(
             pts, cts, iv2, w, k_real=k, block_p=block_p, block_c=block_c,
-            interpret=_interpret_mode(), precision=precision)
+            interpret=default_interpret(), precision=precision)
         return (idx[:n], best[:n], second[:n],
                 m.T[:k, :points.shape[1]], m[points.shape[1], :k],
                 m[points.shape[1] + 1, :k])
     idx, best, second = triton_assign_pallas(
         pts, cts, iv2, k_real=k, block_p=block_p, block_c=block_c,
-        interpret=_interpret_mode(), precision=precision)
+        interpret=default_interpret(), precision=precision)
     return idx[:n], best[:n], second[:n]
 
 

@@ -18,8 +18,9 @@ Two cost terms per (n, d, k, block_p, block_c) sweep:
 * **moment block** — the fused ``[d+2, K]`` accumulator: one
   ``2*BP*(d+2)*K`` one-hot matmul per point tile.
 
-HBM traffic model: the point array streams exactly once (``4*n*d`` bytes
-— the double-buffered DMA hides but does not reduce it), the center block
+HBM traffic model: the point array streams exactly once (``4*n*d``
+logical bytes; the lane padding of a ``[n, d]`` tile is not modeled), the
+center block
 (``4*(d+1)*K``) is re-fetched per point tile, outputs are
 ``12*n`` bytes (idx/best/second) plus the ``4*(d+2)*K`` moment block.
 ``precision="bf16"`` halves the *MXU time* of the distance matmul
@@ -36,9 +37,9 @@ fused hot loop at n=2^20 k=64.
 
 Arithmetic intensity AI = FLOPs / HBM bytes; predicted time =
 max(FLOPs/peak, bytes/bw); utilization = predicted / measured (1.0 =
-running at the roofline). Peaks are per-platform table entries
-(``PLATFORMS``), deliberately coarse — utilization is tracked for
-*regressions*, not absolute truth.
+running at the roofline). Peaks are per-chip table entries
+(``PLATFORMS``, keyed by device kind), deliberately coarse — utilization
+is tracked for *regressions*, not absolute truth.
 """
 from __future__ import annotations
 
@@ -48,33 +49,37 @@ EPILOGUE_FLOPS_PER_CELL = 6.0   # norms add, scale, compare/select chain
 JNP_SCRATCH_PASSES = 4.0        # eff write + argmin + mask + second-min
 
 
-# Per-platform peaks. FLOP/s by distance-matmul precision; bytes/s HBM
-# (or DRAM). TPU numbers per chip (v5e: 197 TF bf16 / 819 GB/s, f32 at
-# half MXU rate); cpu_host is a single container-class x86 core (AVX2 FMA
-# ~1e11 f32 FLOP/s, ~2e10 B/s DRAM; bf16 has no native support); gpu_a100
-# per device for the Mosaic-GPU target.
+# Per-chip peaks, keyed by ``jax.devices()[0].device_kind``. FLOP/s by
+# distance-matmul precision; bytes/s of HBM (or DRAM). TPU v5e ("TPU v5
+# lite"): 197 TFLOP/s bf16 and 16 GB HBM at 819 GB/s (Google Cloud
+# documentation, "TPU v5e"); f32 is modeled at half the bf16 MXU rate.
+# TPU v4: 275 TFLOP/s bf16, 1.2 TB/s (Google Cloud documentation, "TPU
+# v4"). "cpu" is one container-class x86 core (AVX2 FMA ~1e11 f32
+# FLOP/s, ~2e10 B/s DRAM; bf16 has no native support), a model figure and
+# not a measurement.
 PLATFORMS = {
-    "tpu_v5e": {"peak_flops": {"f32": 98.5e12, "bf16": 197e12},
-                "hbm_bw": 819e9},
-    "tpu_v4": {"peak_flops": {"f32": 137.5e12, "bf16": 275e12},
+    "TPU v5 lite": {"peak_flops": {"f32": 98.5e12, "bf16": 197e12},
+                    "hbm_bw": 819e9},
+    "TPU v4": {"peak_flops": {"f32": 137.5e12, "bf16": 275e12},
                "hbm_bw": 1.2e12},
-    "gpu_a100": {"peak_flops": {"f32": 19.5e12, "bf16": 312e12},
-                 "hbm_bw": 1.555e12},
-    "cpu_host": {"peak_flops": {"f32": 1.0e11, "bf16": 1.0e11},
-                 "hbm_bw": 2.0e10},
+    "cpu": {"peak_flops": {"f32": 1.0e11, "bf16": 1.0e11},
+            "hbm_bw": 2.0e10},
 }
 
 
 def detect_platform() -> str:
-    """Map the current jax backend to a PLATFORMS key."""
+    """The ``PLATFORMS`` key of the first jax device (its ``device_kind``).
+
+    Raises:
+        KeyError: the device kind has no entry in the peak table — an
+            unknown chip is an error, never priced as another one.
+    """
     import jax
-    backend = jax.default_backend()
-    if backend == "tpu":
-        kind = jax.devices()[0].device_kind.lower()
-        return "tpu_v4" if "v4" in kind else "tpu_v5e"
-    if backend == "gpu":
-        return "gpu_a100"
-    return "cpu_host"
+    kind = jax.devices()[0].device_kind
+    if kind not in PLATFORMS:
+        raise KeyError(f"no peak-table entry for device_kind {kind!r}; "
+                       f"known kinds: {sorted(PLATFORMS)}")
+    return kind
 
 
 def _pad(x: int, m: int) -> int:

@@ -14,23 +14,19 @@ from __future__ import annotations
 import jax
 
 
-def make_compat_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types across jax versions:
-    jax.sharding.AxisType landed after 0.4.x (where Auto is the only
-    behavior), so the kwarg is passed only when it exists. Use this for
-    every mesh in the repo so the compat rule lives in one place."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(at.Auto,) * len(axes))
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler), the one axis type the model code is written for."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over available (CPU) devices for tests/examples."""
-    return make_compat_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

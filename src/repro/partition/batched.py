@@ -77,13 +77,13 @@ def _build_refine_runner(p1: int, p2: int, cfg: BKMConfig):
     The blocks shard over ``REFINE_AXIS`` alone and are replicated over
     ``COARSE_AXIS`` (every coarse row computes the same block set — the
     blocks are tiny, 1/k1 of the data each, so the redundancy is cheap
-    and keeps the body collective-free). ``check_rep=False`` because the
-    replication is by construction, not by collective.
+    and keeps the body collective-free). Replication checking is off
+    (``dist.rules.shard_map``) because the replication is by construction,
+    not by collective.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.rules import REFINE_AXIS, partition_mesh2d
+    from repro.dist.rules import REFINE_AXIS, partition_mesh2d, shard_map
 
     mesh = partition_mesh2d(p1, p2)
 
@@ -99,8 +99,7 @@ def _build_refine_runner(p1: int, p2: int, cfg: BKMConfig):
     spec = P(REFINE_AXIS)
     return jax.jit(shard_map(local_blocks, mesh=mesh,
                              in_specs=(spec, spec, spec, spec),
-                             out_specs=(spec, spec, spec, spec),
-                             check_rep=False))
+                             out_specs=(spec, spec, spec, spec)))
 
 
 def sharded_batched_balanced_kmeans(points, weights, centers0,

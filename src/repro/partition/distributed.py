@@ -70,7 +70,7 @@ import numpy as np
 from repro.core.balanced_kmeans import BKMConfig, balanced_kmeans
 from repro.core.sfc import sfc_initial_centers, sfc_initial_centers_sharded
 from repro.dist.rules import (COARSE_AXIS, PARTITION_AXIS, REFINE_AXIS,
-                              partition_mesh, partition_mesh2d)
+                              partition_mesh, partition_mesh2d, shard_map)
 from repro.kernels.ops import backend_supports_moments, resolve_assign_backend
 
 from .problem import PartitionProblem, PartitionResult
@@ -376,7 +376,6 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
     with ``warm_start=True`` — the sampled warm-up and the SFC bootstrap
     are both skipped).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if isinstance(devices, tuple):
@@ -406,8 +405,7 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
     inner = shard_map(
         local_fn, mesh=mesh,
         in_specs=(spec, spec, P(), P(), spec),
-        out_specs=(spec, P(), P(), P()),
-        check_rep=False)
+        out_specs=(spec, P(), P(), P()))
     return jax.jit(inner)
 
 

@@ -287,10 +287,9 @@ def _build_lp_runner(devices: int, cap: int, ecap: int, n: int, k: int,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.rules import PARTITION_AXIS, partition_mesh
+    from repro.dist.rules import PARTITION_AXIS, partition_mesh, shard_map
 
     mesh = partition_mesh(devices)
     axis = PARTITION_AXIS
@@ -366,8 +365,7 @@ def _build_lp_runner(devices: int, cap: int, ecap: int, n: int, k: int,
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(), P()),
-        out_specs=(P(axis), P(), P(), P()),
-        check_rep=False)
+        out_specs=(P(axis), P(), P(), P()))
     return jax.jit(inner)
 
 

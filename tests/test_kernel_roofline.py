@@ -29,6 +29,23 @@ def test_detect_platform_is_known():
     assert detect_platform() in PLATFORMS
 
 
+def test_detect_platform_keys_by_device_kind(monkeypatch):
+    """The peak table is keyed by ``device_kind``: v5e resolves to its own
+    entry, and a kind the table does not hold raises instead of being
+    priced as another chip."""
+    import jax
+
+    class _Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v5 lite")])
+    assert detect_platform() == "TPU v5 lite"
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v6e")])
+    with pytest.raises(KeyError, match="TPU v6e"):
+        detect_platform()
+
+
 def test_intensity_positive_and_scales_with_d():
     lo = assign_intensity(1 << 16, 2, 64)
     hi = assign_intensity(1 << 16, 128, 64)
@@ -68,7 +85,7 @@ def test_jnp_memory_model_has_scratch_traffic():
 
 def test_predict_bottleneck_selection():
     # low-d on a bandwidth-starved host: memory bound
-    cpu = predict(1 << 18, 2, 64, platform="cpu_host", backend="jnp")
+    cpu = predict(1 << 18, 2, 64, platform="cpu", backend="jnp")
     assert cpu["bottleneck"] == "memory"
     assert cpu["bound_s"] == pytest.approx(
         max(cpu["compute_s"], cpu["memory_s"]))
@@ -79,8 +96,8 @@ def test_predict_bottleneck_selection():
 
 
 def test_predict_bf16_speeds_distance_only():
-    f32 = predict(1 << 20, 128, 256, platform="tpu_v5e", precision="f32")
-    b16 = predict(1 << 20, 128, 256, platform="tpu_v5e", precision="bf16")
+    f32 = predict(1 << 20, 128, 256, platform="TPU v5 lite", precision="f32")
+    b16 = predict(1 << 20, 128, 256, platform="TPU v5 lite", precision="bf16")
     assert b16["compute_s"] < f32["compute_s"]
     # HBM traffic is modeled unchanged (operands cast in-VMEM)
     assert b16["memory_s"] == f32["memory_s"]
@@ -95,11 +112,11 @@ def test_utilization_edge_cases():
 
 def test_record_schema_complete():
     rec = kernel_roofline_record(1 << 20, 2, 64, measured_s=1.0,
-                                 platform="cpu_host", backend="jnp")
+                                 platform="cpu", backend="jnp")
     for field in ROOFLINE_FIELDS:
         assert field in rec and rec[field] is not None, field
     assert 0.0 < rec["utilization"]
     # without a measurement the record still carries the prediction
-    rec2 = kernel_roofline_record(1 << 20, 2, 64, platform="cpu_host")
+    rec2 = kernel_roofline_record(1 << 20, 2, 64, platform="cpu")
     assert rec2["measured_s"] is None and rec2["utilization"] is None
     assert rec2["bound_s"] > 0

@@ -285,36 +285,6 @@ def test_bf16_precision_within_tolerance(backend):
 
 
 # ---------------------------------------------------------------------------
-# double-buffered point-tile DMA (explicit opt-in under interpret)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n,k,d,bp,bc", [
-    (2048, 64, 2, 256, 32),
-    (1024, 200, 3, 256, 128),
-    (2048, 300, 2, 1024, 256),
-])
-def test_double_buffer_matches_pipelined(n, k, d, bp, bc):
-    """The manual two-slot DMA variant must be bit-identical to the
-    automatically pipelined kernel — same tiles, same arithmetic, only
-    the fetch schedule differs."""
-    pts, ctr, infl = _rand(n, k, d, seed=31)
-    w = jnp.asarray(np.random.default_rng(31).uniform(0.5, 2.0, n),
-                    jnp.float32)
-    a = assign_argmin(pts, ctr, infl, block_p=bp, block_c=bc,
-                      double_buffer=False)
-    b = assign_argmin(pts, ctr, infl, block_p=bp, block_c=bc,
-                      double_buffer=True)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    af = assign_argmin(pts, ctr, infl, block_p=bp, block_c=bc, weights=w,
-                       return_moments=True, double_buffer=False)
-    bf = assign_argmin(pts, ctr, infl, block_p=bp, block_c=bc, weights=w,
-                       return_moments=True, double_buffer=True)
-    for x, y in zip(af, bf):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-
-# ---------------------------------------------------------------------------
 # adaptive default chunk + tile-prune statistic + env override
 # ---------------------------------------------------------------------------
 

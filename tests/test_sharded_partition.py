@@ -189,11 +189,10 @@ def test_warmup_under_shard_map_needs_static_n_global():
     error deep in int(); it must raise an actionable ValueError instead
     (and a static n_global — what the distributed driver passes — must
     keep working)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.balanced_kmeans import BKMConfig, balanced_kmeans
-    from repro.dist.rules import PARTITION_AXIS, partition_mesh
+    from repro.dist.rules import PARTITION_AXIS, partition_mesh, shard_map
 
     mesh = partition_mesh(4)
     pts = np.random.default_rng(0).uniform(0, 1, (1024, 2)).astype(np.float32)
@@ -207,7 +206,7 @@ def test_warmup_under_shard_map_needs_static_n_global():
             return A[None]
         f = jax.jit(shard_map(local, mesh=mesh,
                               in_specs=(P(PARTITION_AXIS), P()),
-                              out_specs=P(PARTITION_AXIS), check_rep=False))
+                              out_specs=P(PARTITION_AXIS)))
         return f(jnp.asarray(pts), jnp.asarray(1024))
 
     # a traced global count cannot size the warm-up schedule

@@ -1,0 +1,114 @@
+"""Compile the assign kernels and the vmapped solve for a TPU v5e chip.
+
+No chip is needed: the TPU compiler compiles for a *described* v5e:2x2
+topology, and refuses what the chip would refuse (block shapes that break
+the (8, 128) tiling rule, unaligned DMA slices, more fast memory than a
+kernel may use) — none of which interpret mode can see. Each test asserts
+that the compiled program holds the Pallas kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+so describing it at import would make test collection differ between
+pytest-xdist workers. Keep these tests in this one file, which one worker
+runs.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+N = 1 << 20                       # the kernel gate size
+BLOCK_P, BLOCK_C = 1024, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:        # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on one described chip, with the persistent compilation
+    cache off (a compile for a described chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_args(sharding, d, k):
+    """Shapes of one kernel call: points, centers padded to a block_c
+    multiple, inverse influence, per-tile bounds, weights."""
+    kpad = -(-k // BLOCK_C) * BLOCK_C
+    return (_spec(sharding, (N, d)), _spec(sharding, (kpad, d)),
+            _spec(sharding, (kpad,)),
+            _spec(sharding, (N // BLOCK_P, kpad // BLOCK_C)),
+            _spec(sharding, (N,)))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["argmin", "reduce"])
+@pytest.mark.parametrize("d,k", [(2, 64), (3, 1024)])
+def test_assign_kernel_compiles(one_chip, fused, d, k):
+    from repro.kernels.assign_kernel import (assign_argmin_pallas,
+                                             assign_reduce_pallas)
+    pts, ctr, inv2, bounds, w = _kernel_args(one_chip, d, k)
+    kw = dict(k_real=k, block_p=BLOCK_P, block_c=BLOCK_C, interpret=False)
+    if fused:
+        lowered = jax.jit(lambda *a: assign_reduce_pallas(*a, **kw)).lower(
+            pts, ctr, inv2, bounds, w)
+    else:
+        lowered = jax.jit(lambda *a: assign_argmin_pallas(*a, **kw)).lower(
+            pts, ctr, inv2, bounds)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_assign_kernel_bf16_compiles(one_chip):
+    from repro.kernels.assign_kernel import assign_reduce_pallas
+    pts, ctr, inv2, bounds, w = _kernel_args(one_chip, 2, 64)
+    fn = jax.jit(lambda *a: assign_reduce_pallas(
+        *a, k_real=64, block_p=BLOCK_P, block_c=BLOCK_C, interpret=False,
+        precision="bf16"))
+    assert "tpu_custom_call" in fn.lower(
+        pts, ctr, inv2, bounds, w).compile().as_text()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Make the solver's kernel calls compile rather than interpret, as
+    they do on a TPU host: ``jax.default_backend()`` is the CPU here.
+    Traces made under the patch are dropped afterwards so no later test in
+    this process reuses them on the CPU."""
+    import repro.kernels.assign_kernel as assign_kernel
+    jax.clear_caches()
+    monkeypatch.setattr(assign_kernel, "default_interpret", lambda: False)
+    yield
+    jax.clear_caches()
+
+
+def test_batched_solve_compiles(one_chip, compiled_kernels):
+    """The hierarchical refinement vmaps the whole solve, kernels
+    included: every block spec must stay legal with the batch dim."""
+    from repro.core.balanced_kmeans import BKMConfig
+    from repro.partition.batched import _batched_jit
+    B, cap, k2 = 8, N // 8, 8
+    cfg = BKMConfig(k=k2, warmup=False, backend="pallas")
+    lowered = _batched_jit.lower(
+        _spec(one_chip, (B, cap, 2)), _spec(one_chip, (B, cap)),
+        _spec(one_chip, (B, k2, 2)), _spec(one_chip, (B,)), cfg)
+    assert "tpu_custom_call" in lowered.compile().as_text()

@@ -16,7 +16,7 @@ from .waivers import Config, Waiver
 FIXTURES: list[tuple[str, bool, str]] = [
     ("SPMD001", True, """
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def build(mesh, spec):
     def local(x):
@@ -25,7 +25,7 @@ def build(mesh, spec):
 """),
     ("SPMD001", False, """
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def build(mesh, spec):
     def local(x):
