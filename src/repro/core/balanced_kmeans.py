@@ -205,6 +205,7 @@ def assign_and_balance(points, w_eff, centers, influence, A_old, ub, lb, cfg,
     d_eff = cfg.d_eff or points.shape[1]
     k, d = cfg.k, points.shape[1]
 
+    @jax.named_scope("balance")
     def body(carry):
         i, A, ub_c, lb_c, infl, _, _, _, _, skips = carry
         idx, best, second, csum, cw, rad2raw = assign_reduce(
@@ -349,6 +350,7 @@ def balanced_kmeans(points, cfg: BKMConfig, weights=None, centers0=None,
 
     hist_len = cfg.max_iter
 
+    @jax.named_scope("movement")
     def body(carry):
         (it, centers, infl, A, ub, lb, _, hist) = carry
         mask = sample_mask(it)
@@ -445,10 +447,11 @@ def balanced_kmeans(points, cfg: BKMConfig, weights=None, centers0=None,
     # final full assignment + balance pass on ALL points (mask = 1) so the
     # returned assignment is exact and balanced even if warm-up dominated
     target = base_target
-    A, infl, ub, lb, sizes, _, _, st = assign_and_balance(
-        points, w, centers, infl, A,
-        jnp.full(n, jnp.inf, dtype), jnp.zeros(n, dtype), cfg, target,
-        axis_name, valid=valid, n_valid=n_global)
+    with jax.named_scope("final_pass"):
+        A, infl, ub, lb, sizes, _, _, st = assign_and_balance(
+            points, w, centers, infl, A,
+            jnp.full(n, jnp.inf, dtype), jnp.zeros(n, dtype), cfg, target,
+            axis_name, valid=valid, n_valid=n_global)
     # tile-pruning effectiveness under the final state: fraction of the
     # kernel's (point-tile x center-tile) grid the bbox bound skips
     # (estimated from the converged second-best; ops.tile_prune_fraction).

@@ -42,26 +42,32 @@ def geographer_partition(points: np.ndarray, k: int,
     which adds the registry, hierarchical (k1 x k2) mode, and quality
     evaluation on top of it.
     """
-    cfg = cfg or BKMConfig(k=k)
-    if cfg.k != k:
-        cfg = replace(cfg, k=k)
-    n = points.shape[0]
-    pts64 = np.asarray(points, dtype=np.float64)
-    centers0 = sfc_initial_centers(pts64, k, weights)
-    # random permutation for the sampled warm-up (paper §4.5)
-    rng = np.random.default_rng(seed)
-    perm = rng.permutation(n)
-    pts = jnp.asarray(pts64[perm], dtype=cfg.dtype)
-    w = None if weights is None else jnp.asarray(np.asarray(weights)[perm],
-                                                 dtype=cfg.dtype)
-    A, centers, infl, stats = _run_jit(pts, cfg, w, jnp.asarray(centers0, cfg.dtype))
-    out = np.empty(n, dtype=np.int64)
-    out[perm] = np.asarray(A)
+    with jax.profiler.TraceAnnotation("repro.bootstrap"):
+        cfg = cfg or BKMConfig(k=k)
+        if cfg.k != k:
+            cfg = replace(cfg, k=k)
+        n = points.shape[0]
+        pts64 = np.asarray(points, dtype=np.float64)
+        centers0 = sfc_initial_centers(pts64, k, weights)
+    with jax.profiler.TraceAnnotation("repro.stage"):
+        # random permutation for the sampled warm-up (paper §4.5)
+        rng = np.random.default_rng(seed)
+        perm = rng.permutation(n)
+        pts, w, c0 = jax.block_until_ready((
+            jnp.asarray(pts64[perm], dtype=cfg.dtype),
+            None if weights is None
+            else jnp.asarray(np.asarray(weights)[perm], dtype=cfg.dtype),
+            jnp.asarray(centers0, cfg.dtype)))
+    with jax.profiler.TraceAnnotation("repro.solve"):
+        solved = jax.block_until_ready(_run_jit(pts, cfg, w, c0))
+    with jax.profiler.TraceAnnotation("repro.fetch"):
+        A, centers, infl, stats = jax.device_get(solved)
+        out = np.empty(n, dtype=np.int64)
+        out[perm] = A
     if return_state:
-        return (out, np.asarray(centers), np.asarray(infl),
-                jax.tree.map(np.asarray, stats))
+        return out, centers, infl, stats
     if return_stats:
-        return out, jax.tree.map(np.asarray, stats)
+        return out, stats
     return out
 
 
@@ -76,7 +82,8 @@ def geographer_repartition(points: np.ndarray, k: int,
                            weights: np.ndarray | None = None,
                            cfg: BKMConfig | None = None,
                            seed: int = 0,
-                           prev_labels: np.ndarray | None = None):
+                           prev_labels: np.ndarray | None = None,
+                           *, attempt: int = 0):
     """Warm-started Geographer: balanced k-means resumed from a previous
     partition's ``(centers0, influence0)`` state, skipping the SFC
     bootstrap and the sampled warm-up entirely (DESIGN.md §8).
@@ -98,6 +105,8 @@ def geographer_repartition(points: np.ndarray, k: int,
                     given, an unchanged-and-still-balanced partition is
                     re-emitted verbatim (no-op detection — zero migration,
                     ``stats["iters"] == 0``).
+        attempt:    which solve of the caller's balance-retry loop this
+                    is; recorded on the ``repro.solve`` trace span.
 
     Returns:
         (labels [n] int64, centers [k, d], influence [k], stats dict).
@@ -109,22 +118,28 @@ def geographer_repartition(points: np.ndarray, k: int,
         cfg = replace(cfg, k=k, warmup=False)
     if centers0.shape[0] != k:
         raise ValueError(f"centers0 has {centers0.shape[0]} rows, k={k}")
-    n = points.shape[0]
-    rng = np.random.default_rng(seed)
-    perm = rng.permutation(n)
-    pts = jnp.asarray(np.asarray(points, np.float64)[perm], dtype=cfg.dtype)
-    w = None if weights is None else jnp.asarray(np.asarray(weights)[perm],
-                                                 dtype=cfg.dtype)
-    infl0 = (None if influence0 is None
-             else jnp.asarray(influence0, cfg.dtype))
-    prev = (None if prev_labels is None
-            else jnp.asarray(np.asarray(prev_labels)[perm], jnp.int32))
-    A, centers, infl, stats = _run_warm_jit(
-        pts, cfg, w, jnp.asarray(centers0, cfg.dtype), infl0, prev)
-    out = np.empty(n, dtype=np.int64)
-    out[perm] = np.asarray(A)
-    return (out, np.asarray(centers), np.asarray(infl),
-            jax.tree.map(np.asarray, stats))
+    with jax.profiler.TraceAnnotation("repro.stage"):
+        n = points.shape[0]
+        rng = np.random.default_rng(seed)
+        perm = rng.permutation(n)
+        pts, w, c0, infl0, prev = jax.block_until_ready((
+            jnp.asarray(np.asarray(points, np.float64)[perm],
+                        dtype=cfg.dtype),
+            None if weights is None
+            else jnp.asarray(np.asarray(weights)[perm], dtype=cfg.dtype),
+            jnp.asarray(centers0, cfg.dtype),
+            None if influence0 is None
+            else jnp.asarray(influence0, cfg.dtype),
+            None if prev_labels is None
+            else jnp.asarray(np.asarray(prev_labels)[perm], jnp.int32)))
+    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt):
+        solved = jax.block_until_ready(
+            _run_warm_jit(pts, cfg, w, c0, infl0, prev))
+    with jax.profiler.TraceAnnotation("repro.fetch"):
+        A, centers, infl, stats = jax.device_get(solved)
+        out = np.empty(n, dtype=np.int64)
+        out[perm] = A
+    return out, centers, infl, stats
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

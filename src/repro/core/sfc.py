@@ -81,9 +81,10 @@ def hilbert_index_np(points: np.ndarray, bits: int | None = None) -> np.ndarray:
     if bits is None:
         bits = 31 if d == 2 else 21
     assert bits * d <= 63, "key must fit int64"
-    q = quantize_np(np.asarray(points, dtype=np.float64), bits)
-    t = _axes_to_transpose_np(q, bits)
-    return _interleave_np(t, bits)
+    with jax.profiler.TraceAnnotation("repro.bootstrap.keys"):
+        q = quantize_np(np.asarray(points, dtype=np.float64), bits)
+        t = _axes_to_transpose_np(q, bits)
+        return _interleave_np(t, bits)
 
 
 # --------------------------------------------------------------------------
@@ -202,7 +203,9 @@ def sfc_order(points: np.ndarray) -> np.ndarray:
     """Stable Hilbert-curve sort order of ``points`` (host-side). Shared by
     the SFC baseline partitioner, initial-center placement, and the
     hierarchical engine's per-block center seeding."""
-    return np.argsort(hilbert_index_np(points), kind="stable")
+    keys = hilbert_index_np(points)
+    with jax.profiler.TraceAnnotation("repro.bootstrap.sort"):
+        return np.argsort(keys, kind="stable")
 
 
 def sfc_initial_centers(points: np.ndarray, k: int,

@@ -465,20 +465,27 @@ def geographer_partition_sharded(problem: PartitionProblem, devices,
         raise ValueError(f"bootstrap must be one of {BOOTSTRAPS}, "
                          f"got {bootstrap!r}")
     cfg = cfg or BKMConfig(k=problem.k, epsilon=problem.epsilon)
-    sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
     if bootstrap == "host":
-        centers0 = sfc_initial_centers(
-            np.asarray(problem.points, np.float64), cfg.k, problem.weights)
+        with jax.profiler.TraceAnnotation("repro.bootstrap"):
+            centers0 = sfc_initial_centers(
+                np.asarray(problem.points, np.float64), cfg.k,
+                problem.weights)
     else:
         centers0 = np.zeros((cfg.k, problem.dim))      # ignored in-graph
-    run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
-                        bootstrap, problem.n)
-    A, centers, infl, stats = run(sp.points, sp.weights,
-                                  jnp.asarray(centers0, cfg.dtype),
-                                  jnp.ones(cfg.k, cfg.dtype),
-                                  jnp.zeros(sp.devices * sp.cap, jnp.int32))
-    labels = sp.scatter_labels(np.asarray(jax.device_get(A)), chunk=chunk)
-    return labels, centers, infl, jax.tree.map(np.asarray, stats)
+    with jax.profiler.TraceAnnotation("repro.stage"):
+        sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
+        run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
+                            bootstrap, problem.n)
+        args = jax.block_until_ready((
+            sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype),
+            jnp.ones(cfg.k, cfg.dtype),
+            jnp.zeros(sp.devices * sp.cap, jnp.int32)))
+    with jax.profiler.TraceAnnotation("repro.solve"):
+        solved = jax.block_until_ready(run(*args))
+    with jax.profiler.TraceAnnotation("repro.fetch"):
+        A, centers, infl, stats = jax.device_get(solved)
+        labels = sp.scatter_labels(A, chunk=chunk)
+    return labels, centers, infl, stats
 
 
 def geographer_repartition_sharded(problem: PartitionProblem, devices,
@@ -486,7 +493,8 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
                                    influence0: np.ndarray | None = None,
                                    cfg: BKMConfig | None = None,
                                    prev_labels: np.ndarray | None = None,
-                                   chunk: int | None = None):
+                                   chunk: int | None = None,
+                                   *, attempt: int = 0):
     """Raw sharded warm-start run: balanced k-means resumed from a previous
     partition's (centers0, influence0) state, no SFC bootstrap.
 
@@ -514,6 +522,8 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
             fire on synthetic labels (locked by
             tests/test_out_of_core.py).
         chunk: per-shard slots per deal slice (None = one shot).
+        attempt: which solve of the caller's balance-retry loop this is;
+            recorded on the ``repro.solve`` trace span.
 
     Returns:
         (labels [n] int64, centers [k, d], influence [k], stats dict);
@@ -527,25 +537,29 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
     if centers0.shape[0] != cfg.k:
         raise ValueError(f"centers0 has {centers0.shape[0]} rows, "
                          f"k={cfg.k}")
-    sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
-    run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
-                        "warm", problem.n)
-    infl0 = (jnp.ones(cfg.k, cfg.dtype) if influence0 is None
-             else jnp.asarray(influence0, cfg.dtype))
-    if prev_labels is None:
-        # synthetic sentinel: -1 never matches a real assignment (block
-        # ids are >= 0), so the no-op shortcut in the core cannot fire on
-        # a partition that never existed — the solver always re-assigns
-        # from (centers0, influence0)
-        prev = np.full((sp.devices, sp.cap), -1, np.int32)
-    else:
-        prev = sp.deal(np.asarray(prev_labels, np.int32), chunk=chunk)
-    A, centers, infl, stats = run(sp.points, sp.weights,
-                                  jnp.asarray(centers0, cfg.dtype),
-                                  infl0,
-                                  jnp.asarray(prev.reshape(-1), jnp.int32))
-    labels = sp.scatter_labels(np.asarray(jax.device_get(A)), chunk=chunk)
-    return labels, centers, infl, jax.tree.map(np.asarray, stats)
+    with jax.profiler.TraceAnnotation("repro.stage"):
+        sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
+        run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
+                            "warm", problem.n)
+        infl0 = (jnp.ones(cfg.k, cfg.dtype) if influence0 is None
+                 else jnp.asarray(influence0, cfg.dtype))
+        if prev_labels is None:
+            # synthetic sentinel: -1 never matches a real assignment
+            # (block ids are >= 0), so the no-op shortcut in the core
+            # cannot fire on a partition that never existed — the solver
+            # always re-assigns from (centers0, influence0)
+            prev = np.full((sp.devices, sp.cap), -1, np.int32)
+        else:
+            prev = sp.deal(np.asarray(prev_labels, np.int32), chunk=chunk)
+        args = jax.block_until_ready((
+            sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype), infl0,
+            jnp.asarray(prev.reshape(-1), jnp.int32)))
+    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt):
+        solved = jax.block_until_ready(run(*args))
+    with jax.profiler.TraceAnnotation("repro.fetch"):
+        A, centers, infl, stats = jax.device_get(solved)
+        labels = sp.scatter_labels(A, chunk=chunk)
+    return labels, centers, infl, stats
 
 
 def partition_sharded(problem: PartitionProblem, devices, *,
@@ -583,7 +597,7 @@ def partition_sharded(problem: PartitionProblem, devices, *,
         problem, devices, cfg=cfg, bootstrap=bootstrap, chunk=chunk)
     return PartitionResult(
         labels=labels, k=problem.k, method="geographer", problem=problem,
-        centers=np.asarray(centers), influence=np.asarray(infl),
+        centers=centers, influence=infl,
         stats={"levels": [dict(stats)],
                "final_imbalance": float(stats["final_imbalance"]),
                "devices": _devices_stat(devices), "bootstrap": bootstrap})
@@ -593,7 +607,7 @@ def repartition_sharded(problem: PartitionProblem, devices,
                         centers0: np.ndarray,
                         influence0: np.ndarray | None = None,
                         prev_labels: np.ndarray | None = None,
-                        chunk: int | None = None,
+                        chunk: int | None = None, *, attempt: int = 0,
                         **opts) -> PartitionResult:
     """Multi-device warm-started repartition (the ``devices=`` path of the
     ``repartition()`` front door).
@@ -607,6 +621,8 @@ def repartition_sharded(problem: PartitionProblem, devices,
             ``repartition()`` always passes them — omitting them deals a
             -1 sentinel that can never masquerade as a real assignment).
         chunk: per-shard slots per deal slice (None = one shot).
+        attempt: the balance-retry attempt, recorded on the
+            ``repro.solve`` trace span.
         **opts: BKMConfig field overrides (``warmup`` is forced off).
 
     Returns:
@@ -618,10 +634,10 @@ def repartition_sharded(problem: PartitionProblem, devices,
     cfg = make_bkm_config(problem, **dict(opts, warmup=False))
     labels, centers, infl, stats = geographer_repartition_sharded(
         problem, devices, centers0, influence0, cfg=cfg,
-        prev_labels=prev_labels, chunk=chunk)
+        prev_labels=prev_labels, chunk=chunk, attempt=attempt)
     return PartitionResult(
         labels=labels, k=problem.k, method="geographer", problem=problem,
-        centers=np.asarray(centers), influence=np.asarray(infl),
+        centers=centers, influence=infl,
         stats={"levels": [dict(stats)],
                "final_imbalance": float(stats["final_imbalance"]),
                "iters": int(stats["iters"]),

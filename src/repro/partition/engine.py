@@ -30,6 +30,10 @@ without changing any result bit.
 """
 from __future__ import annotations
 
+import itertools
+
+import jax
+
 from .hierarchical import hierarchical_partition
 from .problem import PartitionProblem, PartitionResult
 from .registry import (distributed_methods, get_algorithm, resolve_method,
@@ -45,6 +49,10 @@ def _parse_hierarchy(hierarchy) -> tuple[int, int]:
         return int(parts[0]), int(parts[1])
     k1, k2 = hierarchy
     return int(k1), int(k2)
+
+
+#: per-process index of front-door calls, the ``call`` of their trace span
+_CALLS = itertools.count()
 
 
 def partition(problem: PartitionProblem, method: str = "geographer", *,
@@ -94,28 +102,31 @@ def partition(problem: PartitionProblem, method: str = "geographer", *,
         raise TypeError(
             f"partition() takes a PartitionProblem, got {type(problem)}; "
             "wrap raw arrays with PartitionProblem(points=..., k=...)")
-    resolve_method(method)                 # fail fast on unknown names
-    if devices is not None and not supports_devices(method):
-        raise ValueError(
-            f"method {method!r} has no multi-device path; devices= is "
-            f"supported by: {distributed_methods()}")
-    if refine is not None and refine is not False:
-        from .refine import resolve_refiner
-        refine = resolve_refiner(refine)   # fail fast, before the solve
-    else:
-        refine = None
-    if hierarchy is not None:
-        k1, k2 = _parse_hierarchy(hierarchy)
-        result = hierarchical_partition(problem, k1, k2, method=method,
-                                        devices=devices, **opts)
-    else:
-        if devices is not None:
-            opts["devices"] = devices
-        result = get_algorithm(method)(problem, **opts)
-    if refine is not None:
-        from .refine import refine as _refine
-        result = _refine(problem, result, refine, devices=devices,
-                         eps=refine_eps)
-    if evaluate:
-        result.evaluate(with_diameter=with_diameter)
-    return result
+    with jax.profiler.TraceAnnotation("repro.partition", method=method,
+                                      n=problem.n, k=problem.k,
+                                      call=next(_CALLS)):
+        resolve_method(method)                 # fail fast on unknown names
+        if devices is not None and not supports_devices(method):
+            raise ValueError(
+                f"method {method!r} has no multi-device path; devices= is "
+                f"supported by: {distributed_methods()}")
+        if refine is not None and refine is not False:
+            from .refine import resolve_refiner
+            refine = resolve_refiner(refine)   # fail fast, before the solve
+        else:
+            refine = None
+        if hierarchy is not None:
+            k1, k2 = _parse_hierarchy(hierarchy)
+            result = hierarchical_partition(problem, k1, k2, method=method,
+                                            devices=devices, **opts)
+        else:
+            if devices is not None:
+                opts["devices"] = devices
+            result = get_algorithm(method)(problem, **opts)
+        if refine is not None:
+            from .refine import refine as _refine
+            result = _refine(problem, result, refine, devices=devices,
+                             eps=refine_eps)
+        if evaluate:
+            result.evaluate(with_diameter=with_diameter)
+        return result

@@ -27,12 +27,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import numpy as np
 
 from repro.core import metrics
 from repro.core.partitioner import geographer_repartition
 
-from .engine import partition
+from .engine import _CALLS, partition
 from .problem import PartitionProblem, PartitionResult
 from .registry import resolve_method, supports_warm_start
 
@@ -188,9 +189,11 @@ def greedy_center_match(new_centers: np.ndarray,
 
 def _migration_stats(previous: PartitionResult, labels: np.ndarray,
                      weights: np.ndarray | None) -> dict:
-    vol = float(metrics.migration_volume(previous.labels, labels, weights))
-    frac = float(metrics.migration_fraction(previous.labels, labels,
-                                            weights))
+    with jax.profiler.TraceAnnotation("repro.migration"):
+        vol = float(metrics.migration_volume(previous.labels, labels,
+                                             weights))
+        frac = float(metrics.migration_fraction(previous.labels, labels,
+                                                weights))
     return {"volume": vol, "fraction": frac,
             "retained_fraction": 1.0 - frac}
 
@@ -229,7 +232,8 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
     for attempt in range(MAX_BALANCE_RETRIES + 1):
         if devices is not None:
             res = repartition_sharded(problem, devices, centers, infl,
-                                      prev_labels=prev_labels, **opts)
+                                      prev_labels=prev_labels,
+                                      attempt=attempt, **opts)
             iters = res.stats["iters"]
             imb = res.stats["final_imbalance"]
             centers, infl = res.centers, res.influence
@@ -239,7 +243,7 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
             labels, centers, infl, stats = geographer_repartition(
                 problem.points, problem.k, centers, infl,
                 weights=problem.weights, cfg=cfg, seed=problem.seed,
-                prev_labels=prev_labels)
+                prev_labels=prev_labels, attempt=attempt)
             iters = int(stats["iters"])
             imb = float(stats["final_imbalance"])
             res = PartitionResult(
@@ -352,35 +356,38 @@ def repartition(problem: PartitionProblem, previous: PartitionResult,
     if not isinstance(problem, PartitionProblem):
         raise TypeError(
             f"repartition() takes a PartitionProblem, got {type(problem)}")
-    _check_previous(problem, previous)
-    name = resolve_method(method)
-    can_warm = supports_warm_start(name) and previous.centers is not None
-    if warm is None:
-        warm = can_warm
-    elif warm and not supports_warm_start(name):
-        raise ValueError(
-            f"method {name!r} has no warm-start path; warm=True is "
-            "supported by methods registered with supports_warm_start")
-    elif warm and previous.centers is None:
-        raise ValueError(
-            "previous result carries no centers to warm-start from "
-            "(was it produced by a center-based method?)")
+    with jax.profiler.TraceAnnotation("repro.repartition", method=method,
+                                      n=problem.n, k=problem.k,
+                                      call=next(_CALLS)):
+        _check_previous(problem, previous)
+        name = resolve_method(method)
+        can_warm = supports_warm_start(name) and previous.centers is not None
+        if warm is None:
+            warm = can_warm
+        elif warm and not supports_warm_start(name):
+            raise ValueError(
+                f"method {name!r} has no warm-start path; warm=True is "
+                "supported by methods registered with supports_warm_start")
+        elif warm and previous.centers is None:
+            raise ValueError(
+                "previous result carries no centers to warm-start from "
+                "(was it produced by a center-based method?)")
 
-    if refine is not None and refine is not False:
-        from .refine import resolve_refiner
-        refine = resolve_refiner(refine)   # fail fast, before the solve
-    else:
-        refine = None
-    if warm:
-        res = _warm_geographer(problem, previous, devices, **opts)
-    else:
-        res = _cold_relabel(problem, previous, name, devices, **opts)
-    if refine is not None:
-        from .refine import refine as _refine
-        res = _refine(problem, res, refine, devices=devices,
-                      eps=refine_eps)
-    res.stats["migration"] = _migration_stats(previous, res.labels,
-                                              problem.weights)
-    if evaluate:
-        res.evaluate(with_diameter=with_diameter)
-    return res
+        if refine is not None and refine is not False:
+            from .refine import resolve_refiner
+            refine = resolve_refiner(refine)   # fail fast, before the solve
+        else:
+            refine = None
+        if warm:
+            res = _warm_geographer(problem, previous, devices, **opts)
+        else:
+            res = _cold_relabel(problem, previous, name, devices, **opts)
+        if refine is not None:
+            from .refine import refine as _refine
+            res = _refine(problem, res, refine, devices=devices,
+                          eps=refine_eps)
+        res.stats["migration"] = _migration_stats(previous, res.labels,
+                                                  problem.weights)
+        if evaluate:
+            res.evaluate(with_diameter=with_diameter)
+        return res

@@ -13,6 +13,7 @@ pytest-xdist workers. Keep these tests in this one file, which one worker
 runs.
 """
 import os
+import re
 
 import pytest
 
@@ -112,3 +113,25 @@ def test_batched_solve_compiles(one_chip, compiled_kernels):
         _spec(one_chip, (B, cap, 2)), _spec(one_chip, (B, cap)),
         _spec(one_chip, (B, k2, 2)), _spec(one_chip, (B,)), cfg)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_cold_solve_keeps_its_kernel_name_and_scopes(one_chip,
+                                                     compiled_kernels):
+    """The cold solve at an odd n, so that every sweep pads its points to
+    a whole tile: the assign kernel keeps the short HLO name the
+    benchmark's roofline reader keys on, and the op metadata carries the
+    solve's named scopes, by which a profiler groups the chip's ops."""
+    from chipbench.tracefile import short_name
+    from repro.core.balanced_kmeans import BKMConfig
+    from repro.core.partitioner import _run_jit
+    n, k = (1 << 16) + 42, 64
+    cfg = BKMConfig(k=k, backend="pallas")
+    text = _run_jit.lower(_spec(one_chip, (n, 2)), cfg, None,
+                          _spec(one_chip, (k, 2))).compile().as_text()
+    names = {short_name(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines()
+             if line.strip().removeprefix("ROOT ").startswith("%")}
+    assert "assign_reduce_pallas" in names
+    assert "pad" in names
+    for scope in ("movement", "balance", "final_pass"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
