@@ -7,62 +7,148 @@ curve and placing the k initial centers at equal intervals along the curve
 Two implementations are provided:
 
 * ``hilbert_index_np`` — host-side numpy, 64-bit keys (21 bits/dim in 3D,
-  31 bits/dim in 2D). Used by the data pipeline and benchmarks.
+  31 bits/dim in 2D). Used by the host SFC bootstrap (``sfc_order``), the
+  data pipeline and benchmarks.
 * ``hilbert_index_jnp`` — in-graph jax version with 30-bit keys (15 bits/dim
   in 2D, 10 bits/dim in 3D) that fit int32. Used inside jitted partitioning
   steps and by the distributed partitioner.
 
-Both use Skilling's transpose algorithm ("Programming the Hilbert curve",
-AIP 2004), which is branch-free over the point axis and therefore
-vectorizes cleanly on both numpy and the TPU VPU.
+Both compute the curve of Skilling's transpose algorithm ("Programming the
+Hilbert curve", AIP 2004). The jax version runs it one bit level at a time,
+branch-free over the point axis, which vectorizes cleanly on the TPU VPU.
+The numpy version runs it as a finite-state transducer: what the levels
+above have done to a level is a state (an axis permutation, a flip per
+axis, a Gray parity bit; 8 are reachable in 2D, 48 in 3D), so a table
+derived from the transform (``_hilbert_table``) maps a state and the next c
+bit levels of every axis to their d·c key bits and the next state in one
+gather. Its keys are those of
+the per-bit transform, bit for bit, from about a dozen array passes per c
+levels instead of about thirty per level.
 """
 from __future__ import annotations
+
+import functools
+import math
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 
-def _axes_to_transpose_np(X: np.ndarray, bits: int) -> np.ndarray:
-    """Skilling inverse-undo + Gray encode. X: [n, d] uint64, returns [n, d]."""
-    X = X.copy()
-    n, d = X.shape
-    M = np.uint64(1) << np.uint64(bits - 1)
-    # Inverse undo excess work
-    Q = M
-    while Q > np.uint64(1):
-        Pm = Q - np.uint64(1)
-        for i in range(d):
-            flag = (X[:, i] & Q) != 0
-            # where flag: invert low bits of X[:,0]
-            X[:, 0] = np.where(flag, X[:, 0] ^ Pm, X[:, 0])
-            # else: exchange low bits of X[:,0] and X[:,i]
-            t = np.where(~flag, (X[:, 0] ^ X[:, i]) & Pm, np.uint64(0))
-            X[:, 0] ^= t
-            X[:, i] ^= t
-        Q >>= np.uint64(1)
-    # Gray encode
-    for i in range(1, d):
-        X[:, i] ^= X[:, i - 1]
-    t = np.zeros(n, dtype=np.uint64)
-    Q = M
-    while Q > np.uint64(1):
-        flag = (X[:, d - 1] & Q) != 0
-        t = np.where(flag, t ^ (Q - np.uint64(1)), t)
-        Q >>= np.uint64(1)
-    for i in range(d):
-        X[:, i] ^= t
-    return X
+# --------------------------------------------------------------------------
+# numpy version (uint64 keys; 31 bits/dim 2D, 21 bits/dim 3D)
+# --------------------------------------------------------------------------
+
+_TABLE_BYTES = 1 << 17  # a transition table small enough to stay in cache
+_BLOCK = 1 << 16        # points a block: the key loop's arrays stay in cache
 
 
-def _interleave_np(X: np.ndarray, bits: int) -> np.ndarray:
-    """Bit-interleave the transposed form into a single key. X: [n, d]."""
-    n, d = X.shape
-    key = np.zeros(n, dtype=np.uint64)
-    for b in range(bits - 1, -1, -1):
-        for i in range(d):
-            key = (key << np.uint64(1)) | ((X[:, i] >> np.uint64(b)) & np.uint64(1))
-    return key
+def _chunk_levels(d: int) -> int:
+    """Bit levels of every axis that one lookup consumes: the most for which
+    a table of d!·2^d states × 2^(d·c) inputs of uint16 fits
+    ``_TABLE_BYTES`` (6 in 2D, 3 in 3D)."""
+    states = math.factorial(d) << d
+    c = 1
+    while states << (d * (c + 1) + 1) <= _TABLE_BYTES:
+        c += 1
+    return c
+
+
+@functools.cache
+def _hilbert_table(d: int, c: int) -> np.ndarray:
+    """Skilling's transform as a transducer over c bit levels at a time.
+
+    The transform walks the bit levels from the top. At each level it reads
+    one bit per axis, then for i = 0..d-1 inverts X0 below the level if
+    Xi's bit is set, else swaps X0 and Xi below it; the Gray code XORs the
+    axes' bits in prefix order and flips every level below a set bit of the
+    last axis. So what the levels above have done to a level is a state: an
+    axis permutation, a flip per axis and one parity bit.
+
+    Entry ``state << d·c | input`` holds the d·c key bits of the c levels
+    (top level first, axis 0 first within a level) and, above them, the
+    state they leave. ``input`` holds axis 0's c bits (top level first)
+    above axis 1's, and so on. States are numbered breadth-first from the
+    identity, 0; the walk reaches 8 of them in 2D and 48 in 3D.
+    """
+    m = d * c
+    x = np.arange(1 << m)
+    field = (d - 1 - np.arange(d)) * c       # each axis's bits in an input
+    weight = 1 << (d - 1 - np.arange(d))     # each axis's key bit in a level
+    states = [(tuple(range(d)), (0,) * d, 0)]  # (perm, flip, parity)
+    number = {states[0]: 0}
+    rows = []
+    done = 0
+    while done < len(states):
+        frontier, done = states[done:], len(states)
+        perm = np.repeat([s[0] for s in frontier], x.size, axis=0)
+        flip = np.repeat([s[1] for s in frontier], x.size, axis=0)
+        parity = np.repeat([s[2] for s in frontier], x.size)
+        inputs = np.tile(x, len(frontier))
+        key = np.zeros_like(inputs)
+        for level in range(c - 1, -1, -1):
+            raw = (inputs[:, None] >> (field + level)) & 1
+            b = np.take_along_axis(raw, perm, axis=1) ^ flip  # bits the axes show
+            for i in range(d):
+                flip[:, 0] ^= b[:, i]                          # invert X0
+                swap = (b[:, i] == 0)[:, None]                 # or swap X0, Xi
+                for a in (perm, flip):
+                    a[:, [0, i]] = np.where(swap, a[:, [i, 0]], a[:, [0, i]])
+            gray = np.bitwise_xor.accumulate(b, axis=1)
+            key = key << d | ((gray ^ parity[:, None]) * weight).sum(axis=1)
+            parity = parity ^ gray[:, -1]
+        after = np.column_stack([perm, flip, parity])
+        code = after @ (max(d, 2) ** np.arange(2 * d + 1))
+        _, first, inverse = np.unique(code, return_index=True,
+                                      return_inverse=True)
+        ids = []
+        for row in after[first].tolist():
+            s = (tuple(row[:d]), tuple(row[d:2 * d]), row[-1])
+            if s not in number:
+                number[s] = len(states)
+                states.append(s)
+            ids.append(number[s])
+        rows.append(key | np.asarray(ids)[inverse.ravel()] << m)
+    table = np.concatenate(rows)
+    return table.astype(np.min_scalar_type(int(table.max())))
+
+
+def _hilbert_keys(q: np.ndarray, bits: int) -> np.ndarray:
+    """Hilbert keys of grid points q [n, d] (coordinates < 2^bits), one
+    table lookup per c levels. A last chunk that reaches below level 0
+    reads zeros there, and its key bits for those levels are dropped."""
+    n, d = q.shape
+    c = _chunk_levels(d)
+    table = _hilbert_table(d, c)
+    m = d * c
+    key_mask = (1 << m) - 1
+    state_mask = np.iinfo(table.dtype).max ^ key_mask
+    field = [(d - 1 - a) * c for a in range(d)]
+    keys = np.zeros(n, np.uint64)
+    for lo in range(0, n, _BLOCK):
+        key = keys[lo:lo + _BLOCK]
+        cols = [q[lo:lo + _BLOCK, a].astype(np.uint32) for a in range(d)]
+        entry = np.zeros(key.size, table.dtype)  # state 0, the identity
+        idx = np.empty(key.size, np.intp)
+        part = np.empty(key.size, np.uint32)
+        for top in range(bits, 0, -c):  # levels [top - c, top)
+            np.bitwise_and(entry, state_mask, out=idx)
+            for a in range(d):
+                shift = top - c - field[a]
+                if shift >= 0:
+                    np.right_shift(cols[a], shift, out=part)
+                else:
+                    np.left_shift(cols[a], -shift, out=part)
+                np.bitwise_and(part, ((1 << c) - 1) << field[a], out=part)
+                np.bitwise_or(idx, part, out=idx)
+            np.take(table, idx, out=entry)
+            w = d * min(c, top)  # key bits of levels >= 0
+            np.left_shift(key, w, out=key)
+            np.bitwise_and(entry, key_mask, out=part)
+            if w < m:
+                np.right_shift(part, m - w, out=part)
+            np.bitwise_or(key, part, out=key)
+    return keys
 
 
 def quantize_np(points: np.ndarray, bits: int) -> np.ndarray:
@@ -83,8 +169,7 @@ def hilbert_index_np(points: np.ndarray, bits: int | None = None) -> np.ndarray:
     assert bits * d <= 63, "key must fit int64"
     with jax.profiler.TraceAnnotation("repro.bootstrap.keys"):
         q = quantize_np(np.asarray(points, dtype=np.float64), bits)
-        t = _axes_to_transpose_np(q, bits)
-        return _interleave_np(t, bits)
+        return _hilbert_keys(q, bits)
 
 
 # --------------------------------------------------------------------------
