@@ -58,7 +58,7 @@ def geographer_partition(points: np.ndarray, k: int,
             None if weights is None
             else jnp.asarray(np.asarray(weights)[perm], dtype=cfg.dtype),
             jnp.asarray(centers0, cfg.dtype)))
-    with jax.profiler.TraceAnnotation("repro.solve"):
+    with jax.profiler.TraceAnnotation("repro.solve", slots=n):
         solved = jax.block_until_ready(_run_jit(pts, cfg, w, c0))
     with jax.profiler.TraceAnnotation("repro.fetch"):
         A, centers, infl, stats = jax.device_get(solved)
@@ -76,6 +76,22 @@ def _run_jit(points, cfg, weights, centers0):
     return balanced_kmeans(points, cfg, weights, centers0)
 
 
+def warm_slots(n: int) -> int:
+    """The padded point count of a warm solve over a point set that
+    changes from call to call: ``n`` rounded up to a multiple of
+    ``2 ** (floor(log2 n) - 3)``, eight buckets an octave.
+
+    A jitted solve is compiled for one point count, so a mesh that
+    refines or coarsens by ~1% a step would compile on every step; over
+    the buckets it compiles once per bucket, and padding stays under
+    12.5% of the slots. The sharded path rounds its per-shard ``cap`` by
+    the same rule, so ``devices=1`` pads exactly as the flat path does.
+    """
+    n = int(n)
+    step = 1 << max(n.bit_length() - 4, 0)
+    return -(-n // step) * step
+
+
 def geographer_repartition(points: np.ndarray, k: int,
                            centers0: np.ndarray,
                            influence0: np.ndarray | None = None,
@@ -83,7 +99,7 @@ def geographer_repartition(points: np.ndarray, k: int,
                            cfg: BKMConfig | None = None,
                            seed: int = 0,
                            prev_labels: np.ndarray | None = None,
-                           *, attempt: int = 0):
+                           *, attempt: int = 0, pad: bool = False):
     """Warm-started Geographer: balanced k-means resumed from a previous
     partition's ``(centers0, influence0)`` state, skipping the SFC
     bootstrap and the sampled warm-up entirely (DESIGN.md §8).
@@ -107,6 +123,11 @@ def geographer_repartition(points: np.ndarray, k: int,
                     ``stats["iters"] == 0``).
         attempt:    which solve of the caller's balance-retry loop this
                     is; recorded on the ``repro.solve`` trace span.
+        pad:        solve over ``warm_slots(n)`` slots, so that calls
+                    whose point counts share a bucket share one compiled
+                    solve (a point set that changes from step to step).
+                    Pad slots replicate real points at weight zero, the
+                    sharded path's convention; their labels are dropped.
 
     Returns:
         (labels [n] int64, centers [k, d], influence [k], stats dict).
@@ -122,32 +143,43 @@ def geographer_repartition(points: np.ndarray, k: int,
         n = points.shape[0]
         rng = np.random.default_rng(seed)
         perm = rng.permutation(n)
+        slots = warm_slots(n) if pad else n
+        idx = perm if slots == n else np.resize(perm, slots)
+        if pad:
+            w = (np.ones(n, cfg.dtype) if weights is None
+                 else np.asarray(weights, cfg.dtype))[idx]
+            w[n:] = 0
+            n_valid = np.int32(n)
+        else:
+            w = None if weights is None else np.asarray(weights)[perm]
+            n_valid = None
         pts, w, c0, infl0, prev = jax.block_until_ready((
-            jnp.asarray(np.asarray(points, np.float64)[perm],
+            jnp.asarray(np.asarray(points, np.float64)[idx],
                         dtype=cfg.dtype),
-            None if weights is None
-            else jnp.asarray(np.asarray(weights)[perm], dtype=cfg.dtype),
+            None if w is None else jnp.asarray(w, dtype=cfg.dtype),
             jnp.asarray(centers0, cfg.dtype),
             None if influence0 is None
             else jnp.asarray(influence0, cfg.dtype),
             None if prev_labels is None
-            else jnp.asarray(np.asarray(prev_labels)[perm], jnp.int32)))
-    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt):
+            else jnp.asarray(np.asarray(prev_labels)[idx], jnp.int32)))
+    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt,
+                                      slots=slots):
         solved = jax.block_until_ready(
-            _run_warm_jit(pts, cfg, w, c0, infl0, prev))
+            _run_warm_jit(pts, cfg, w, c0, infl0, prev, n_valid))
     with jax.profiler.TraceAnnotation("repro.fetch"):
         A, centers, infl, stats = jax.device_get(solved)
         out = np.empty(n, dtype=np.int64)
-        out[perm] = A
+        out[perm] = A[:n]
     return out, centers, infl, stats
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _run_warm_jit(points, cfg, weights, centers0, influence0,
-                  prev_assignment):
+                  prev_assignment, n_valid=None):
     return balanced_kmeans(points, cfg, weights, centers0,
                            influence0=influence0, warm_start=True,
-                           prev_assignment=prev_assignment)
+                           prev_assignment=prev_assignment,
+                           n_global=n_valid)
 
 
 # ---------------------------------------------------------------------------

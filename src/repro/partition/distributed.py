@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.balanced_kmeans import BKMConfig, balanced_kmeans
+from repro.core.partitioner import warm_slots
 from repro.core.sfc import sfc_initial_centers, sfc_initial_centers_sharded
 from repro.dist.rules import (COARSE_AXIS, PARTITION_AXIS, REFINE_AXIS,
                               partition_mesh, partition_mesh2d, shard_map)
@@ -194,13 +195,15 @@ class ShardedPartitionProblem:
 
     @property
     def cap(self) -> int:
-        """Per-shard slot count, ``ceil(n / P)``."""
+        """Per-shard slot count, ``ceil(n / P)`` unless the deal was
+        given a larger one."""
         return self.points.shape[1]
 
     @classmethod
     def from_problem(cls, problem: PartitionProblem, devices, *,
                      chunk: int | None = None, commit: bool = False,
-                     dtype=None, mesh=None) -> "ShardedPartitionProblem":
+                     dtype=None, mesh=None,
+                     cap: int | None = None) -> "ShardedPartitionProblem":
         """Deal ``problem`` onto ``devices`` shards.
 
         The deal streams in bounded slot slices: each slice gathers
@@ -227,20 +230,28 @@ class ShardedPartitionProblem:
                 dtype; commit respects jax's x64 setting).
             mesh: device mesh for ``commit`` (None = the 1-D or 2-D
                 partition mesh implied by ``devices``).
+            cap: per-shard slots (None = ``ceil(n / P)``); the slots
+                past the points are padding.
 
         Returns:
             The static-shape sharded view.
 
         Raises:
-            ValueError: P < 1, P > n, or an int32 index-capacity
-                overflow (``check_index_capacity``).
+            ValueError: P < 1, P > n, ``cap`` below ``ceil(n / P)``, or
+                an int32 index-capacity overflow
+                (``check_index_capacity``).
         """
         shape = _device_shape(devices)
         P = int(np.prod(shape))
         n = problem.n
         if P > n:
             raise ValueError(f"devices={P} exceeds n={n} points")
-        cap = check_index_capacity(n, P)
+        least = check_index_capacity(n, P)
+        if cap is None:
+            cap = least
+        elif not least <= cap <= INT32_INDEX_CAP:
+            raise ValueError(f"cap={cap} must lie in [ceil(n/P)={least}, "
+                             f"{INT32_INDEX_CAP}]")
         rng = np.random.default_rng(problem.seed)
         perm = rng.permutation(n)
         src = np.asarray(problem.points)
@@ -360,7 +371,7 @@ class ShardedPartitionProblem:
 
 @functools.lru_cache(maxsize=64)
 def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
-                  bootstrap: str, n_global: int):
+                  bootstrap: str, n_global: int | None):
     """Compile-cached shard_map driver for one (mesh, shapes, cfg) combo.
 
     ``devices`` is an int (1-D ``PARTITION_AXIS`` mesh) or a (P1, P2)
@@ -374,7 +385,10 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
     bootstrap; centers0 input ignored), or "warm" (centers0 AND influence0
     are the replicated previous-partition state and the k-means core runs
     with ``warm_start=True`` — the sampled warm-up and the SFC bootstrap
-    are both skipped).
+    are both skipped). A warm runner reads the real point count from its
+    ``n_valid`` input (``n_global`` is None), so one compiled runner
+    serves every point count that fits its ``cap``; cold runners keep
+    the static ``n_global`` their warm-up rounds need.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -387,7 +401,8 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
         axis = PARTITION_AXIS
         spec = P(axis)
 
-    def local_fn(points, weights, centers0, influence0, prev_labels):
+    def local_fn(points, weights, centers0, influence0, prev_labels,
+                 n_valid):
         points = points.reshape(cap, dim)
         weights = weights.reshape(cap)
         if bootstrap == "device":
@@ -396,7 +411,8 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
                 cfg.k, axis)
         A, centers, infl, stats = balanced_kmeans(
             points, cfg, weights, centers0.astype(cfg.dtype),
-            axis_name=axis, n_global=n_global,
+            axis_name=axis,
+            n_global=n_valid if bootstrap == "warm" else n_global,
             influence0=influence0, warm_start=(bootstrap == "warm"),
             prev_assignment=(prev_labels.reshape(cap)
                              if bootstrap == "warm" else None))
@@ -404,7 +420,7 @@ def _build_runner(devices, cap: int, dim: int, cfg: BKMConfig,
 
     inner = shard_map(
         local_fn, mesh=mesh,
-        in_specs=(spec, spec, P(), P(), spec),
+        in_specs=(spec, spec, P(), P(), spec, P()),
         out_specs=(spec, P(), P(), P()))
     return jax.jit(inner)
 
@@ -416,18 +432,20 @@ def _runner_key(devices):
 
 
 def _prep_sharded_cfg(problem: PartitionProblem, devices,
-                      cfg: BKMConfig, chunk: int | None = None):
+                      cfg: BKMConfig, chunk: int | None = None,
+                      cap: int | None = None):
     """Shard the problem (placement-committed in the solve dtype, so the
     drivers stage no further host copies) and pin cfg's "auto" backend AND
     its fused assign+reduce choice to concrete values *before* tracing the
     shard_map body (both depend on process-global state, not trace-local
     state). Returns (sharded, cfg). The fused sweep keeps the paper's
     psum-only communication contract: per balance iteration one [k] size
-    sum, per movement iteration one [k, d] + one [k] moment sum."""
+    sum, per movement iteration one [k, d] + one [k] moment sum. ``cap``
+    is the per-shard slot count (None = ``ceil(n / P)``)."""
     shape = _device_shape(devices)
     sp = ShardedPartitionProblem.from_problem(
         problem, devices, chunk=chunk, commit=True, dtype=cfg.dtype,
-        mesh=_mesh_for_shape(shape))
+        mesh=_mesh_for_shape(shape), cap=cap)
     backend = resolve_assign_backend(cfg.assign_backend, sharded=True,
                                      n_local=sp.cap)
     fused = (backend_supports_moments(backend) if cfg.fused is None
@@ -479,8 +497,10 @@ def geographer_partition_sharded(problem: PartitionProblem, devices,
         args = jax.block_until_ready((
             sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype),
             jnp.ones(cfg.k, cfg.dtype),
-            jnp.zeros(sp.devices * sp.cap, jnp.int32)))
-    with jax.profiler.TraceAnnotation("repro.solve"):
+            jnp.zeros(sp.devices * sp.cap, jnp.int32),
+            jnp.int32(problem.n)))
+    with jax.profiler.TraceAnnotation("repro.solve",
+                                      slots=sp.devices * sp.cap):
         solved = jax.block_until_ready(run(*args))
     with jax.profiler.TraceAnnotation("repro.fetch"):
         A, centers, infl, stats = jax.device_get(solved)
@@ -494,7 +514,7 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
                                    cfg: BKMConfig | None = None,
                                    prev_labels: np.ndarray | None = None,
                                    chunk: int | None = None,
-                                   *, attempt: int = 0):
+                                   *, attempt: int = 0, pad: bool = False):
     """Raw sharded warm-start run: balanced k-means resumed from a previous
     partition's (centers0, influence0) state, no SFC bootstrap.
 
@@ -524,6 +544,10 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
         chunk: per-shard slots per deal slice (None = one shot).
         attempt: which solve of the caller's balance-retry loop this is;
             recorded on the ``repro.solve`` trace span.
+        pad: round the per-shard ``cap`` up by
+            ``core.partitioner.warm_slots``, as the flat path pads its
+            point count, so that point counts of one bucket share one
+            compiled runner.
 
     Returns:
         (labels [n] int64, centers [k, d], influence [k], stats dict);
@@ -538,9 +562,12 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
         raise ValueError(f"centers0 has {centers0.shape[0]} rows, "
                          f"k={cfg.k}")
     with jax.profiler.TraceAnnotation("repro.stage"):
-        sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
+        cap = (warm_slots(check_index_capacity(problem.n, devices)) if pad
+               else None)
+        sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk,
+                                    cap=cap)
         run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
-                            "warm", problem.n)
+                            "warm", None)
         infl0 = (jnp.ones(cfg.k, cfg.dtype) if influence0 is None
                  else jnp.asarray(influence0, cfg.dtype))
         if prev_labels is None:
@@ -553,8 +580,10 @@ def geographer_repartition_sharded(problem: PartitionProblem, devices,
             prev = sp.deal(np.asarray(prev_labels, np.int32), chunk=chunk)
         args = jax.block_until_ready((
             sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype), infl0,
-            jnp.asarray(prev.reshape(-1), jnp.int32)))
-    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt):
+            jnp.asarray(prev.reshape(-1), jnp.int32),
+            jnp.int32(problem.n)))
+    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt,
+                                      slots=sp.devices * sp.cap):
         solved = jax.block_until_ready(run(*args))
     with jax.profiler.TraceAnnotation("repro.fetch"):
         A, centers, infl, stats = jax.device_get(solved)
@@ -608,7 +637,7 @@ def repartition_sharded(problem: PartitionProblem, devices,
                         influence0: np.ndarray | None = None,
                         prev_labels: np.ndarray | None = None,
                         chunk: int | None = None, *, attempt: int = 0,
-                        **opts) -> PartitionResult:
+                        pad: bool = False, **opts) -> PartitionResult:
     """Multi-device warm-started repartition (the ``devices=`` path of the
     ``repartition()`` front door).
 
@@ -623,6 +652,8 @@ def repartition_sharded(problem: PartitionProblem, devices,
         chunk: per-shard slots per deal slice (None = one shot).
         attempt: the balance-retry attempt, recorded on the
             ``repro.solve`` trace span.
+        pad: pad the shards as ``geographer_repartition_sharded`` says
+            (a point set that changes from step to step).
         **opts: BKMConfig field overrides (``warmup`` is forced off).
 
     Returns:
@@ -634,7 +665,7 @@ def repartition_sharded(problem: PartitionProblem, devices,
     cfg = make_bkm_config(problem, **dict(opts, warmup=False))
     labels, centers, infl, stats = geographer_repartition_sharded(
         problem, devices, centers0, influence0, cfg=cfg,
-        prev_labels=prev_labels, chunk=chunk, attempt=attempt)
+        prev_labels=prev_labels, chunk=chunk, attempt=attempt, pad=pad)
     return PartitionResult(
         labels=labels, k=problem.k, method="geographer", problem=problem,
         centers=centers, influence=infl,

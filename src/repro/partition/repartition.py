@@ -17,6 +17,13 @@ fraction of the weight a cold restart would (DESIGN.md §8)::
     res.stats["migration"]["fraction"]                    # weight moved
     res.stats["iters"]                                    # ~0-5, not ~30
 
+A mesh that is refined or coarsened between steps has another point
+count: ``identity`` says, for each new point, its index in the previous
+point set, or -1 for a point that refinement created::
+
+    res2 = repartition(prob2, res, identity=idx)          # n changed
+    res2.stats["migration"]["created"]                    # new weight
+
 Methods without a warm-startable state (sfc/rcb/rib/multijagged — their
 partitions are recomputed from scratch) fall back to a **cold start +
 relabel matching**: new blocks are greedily matched to the previous blocks
@@ -188,43 +195,80 @@ def greedy_center_match(new_centers: np.ndarray,
 
 
 def _migration_stats(previous: PartitionResult, labels: np.ndarray,
-                     weights: np.ndarray | None) -> dict:
+                     weights: np.ndarray | None,
+                     identity: np.ndarray | None) -> dict:
+    """Migration against ``previous`` under the new weights. With an
+    ``identity`` map it is counted over the persisting points (their
+    previous labels carried through the map), and the weight of the
+    created points is reported apart as ``created``."""
     with jax.profiler.TraceAnnotation("repro.migration"):
-        vol = float(metrics.migration_volume(previous.labels, labels,
-                                             weights))
-        frac = float(metrics.migration_fraction(previous.labels, labels,
-                                                weights))
+        prev, new, w, created = previous.labels, labels, weights, 0.0
+        if identity is not None:
+            kept = identity >= 0
+            prev = np.asarray(previous.labels)[identity[kept]]
+            new = np.asarray(labels)[kept]
+            if weights is None:
+                created = float(kept.size - np.count_nonzero(kept))
+            else:
+                w = np.asarray(weights)[kept]
+                created = float(np.sum(np.asarray(weights)[~kept]))
+        vol = float(metrics.migration_volume(prev, new, w))
+        frac = float(metrics.migration_fraction(prev, new, w))
     return {"volume": vol, "fraction": frac,
-            "retained_fraction": 1.0 - frac}
+            "retained_fraction": 1.0 - frac, "created": created}
 
 
-def _check_previous(problem: PartitionProblem, previous: PartitionResult):
+def _check_previous(problem: PartitionProblem, previous: PartitionResult,
+                    identity) -> np.ndarray | None:
+    """Validates ``previous`` (and ``identity``) against ``problem``;
+    returns the identity map as int64, or None."""
     if not isinstance(previous, PartitionResult):
         raise TypeError(f"previous must be a PartitionResult, got "
                         f"{type(previous)}")
     if previous.k != problem.k:
         raise ValueError(f"previous partition has k={previous.k}, "
                          f"problem has k={problem.k}")
-    if len(previous.labels) != problem.n:
+    n_prev = len(previous.labels)
+    if identity is None:
+        if n_prev != problem.n:
+            raise ValueError(
+                f"previous partition labels {n_prev} points, problem has "
+                f"n={problem.n} (repartition requires the same point set, "
+                "possibly moved or re-weighted, unless identity= maps the "
+                "new points to the previous ones)")
+        return None
+    identity = np.asarray(identity)
+    if identity.shape != (problem.n,) or not np.issubdtype(identity.dtype,
+                                                           np.integer):
         raise ValueError(
-            f"previous partition labels {len(previous.labels)} points, "
-            f"problem has n={problem.n} (repartition requires the same "
-            "point set, possibly moved or re-weighted)")
+            f"identity must be [n={problem.n}] integers, got "
+            f"{identity.dtype} {identity.shape}")
+    if problem.n and (identity.min() < -1 or identity.max() >= n_prev):
+        raise ValueError(
+            f"identity holds indices outside [-1, {n_prev}): each new "
+            "point's index in the previous point set, or -1")
+    return identity.astype(np.int64, copy=False)
 
 
 def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
-                     devices: int | None, **opts) -> PartitionResult:
+                     devices: int | None, changed: bool,
+                     **opts) -> PartitionResult:
     """Warm-started balanced k-means (+ balance-retry loop): the engine's
     one warm-start implementation, shared by every method whose registry
     entry declares ``supports_warm_start`` (currently the geographer
-    family — a new warm-capable algorithm needs its own branch here)."""
+    family — a new warm-capable algorithm needs its own branch here).
+
+    ``changed``: the point set is not the previous one. The solve then
+    resumes from the previous centers and influence alone (they are per
+    block and carry over), without no-op detection, over a point count
+    padded to its ``warm_slots`` bucket."""
     from .algorithms import make_bkm_config
     from .distributed import repartition_sharded
     opts.setdefault("delta_tol", WARM_DELTA_TOL)
     opts["warmup"] = False
     state = WarmState.capture(previous)
     centers, infl = state.centers, state.influence
-    prev_labels = state.labels
+    prev_labels = None if changed else state.labels
     # the solver balances against the caller's effective epsilon (an
     # opts override wins over the problem's), so the retry check must too
     eps_eff = opts.get("epsilon", problem.epsilon)
@@ -233,7 +277,7 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
         if devices is not None:
             res = repartition_sharded(problem, devices, centers, infl,
                                       prev_labels=prev_labels,
-                                      attempt=attempt, **opts)
+                                      attempt=attempt, pad=changed, **opts)
             iters = res.stats["iters"]
             imb = res.stats["final_imbalance"]
             centers, infl = res.centers, res.influence
@@ -243,7 +287,7 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
             labels, centers, infl, stats = geographer_repartition(
                 problem.points, problem.k, centers, infl,
                 weights=problem.weights, cfg=cfg, seed=problem.seed,
-                prev_labels=prev_labels, attempt=attempt)
+                prev_labels=prev_labels, attempt=attempt, pad=changed)
             iters = int(stats["iters"])
             imb = float(stats["final_imbalance"])
             res = PartitionResult(
@@ -253,7 +297,8 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
         total_iters += iters
         if imb <= eps_eff + 1e-6:
             break
-        prev_labels = np.asarray(labels)
+        if not changed:
+            prev_labels = np.asarray(labels)
     res.stats.update({"warm_start": True, "iters": total_iters,
                       "balance_retries": attempt})   # re-warm solves run
     return res
@@ -261,12 +306,19 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
 
 def _cold_relabel(problem: PartitionProblem, previous: PartitionResult,
                   method: str, devices: int | None,
-                  **opts) -> PartitionResult:
+                  identity: np.ndarray | None, **opts) -> PartitionResult:
     res = partition(problem, method=method, devices=devices, **opts)
-    prev_centers = (np.asarray(previous.centers)
-                    if previous.centers is not None else
-                    weighted_centroids(problem.points, previous.labels,
-                                       problem.k, problem.weights))
+    if previous.centers is not None:
+        prev_centers = np.asarray(previous.centers)
+    else:
+        # the previous blocks' centroids over the persisting points
+        pts, prev, w = problem.points, previous.labels, problem.weights
+        if identity is not None:
+            kept = identity >= 0
+            pts = np.asarray(pts)[kept]
+            prev = np.asarray(prev)[identity[kept]]
+            w = None if w is None else np.asarray(w)[kept]
+        prev_centers = weighted_centroids(pts, prev, problem.k, w)
     new_centers = (np.asarray(res.centers) if res.centers is not None else
                    weighted_centroids(problem.points, res.labels,
                                       problem.k, problem.weights))
@@ -300,6 +352,7 @@ def _stats_iters(res: PartitionResult):
 
 def repartition(problem: PartitionProblem, previous: PartitionResult,
                 method: str = "geographer", *,
+                identity: np.ndarray | None = None,
                 devices: int | None = None, warm: bool | None = None,
                 refine=None, refine_eps: float | None = None,
                 evaluate: bool = False, with_diameter: bool = False,
@@ -310,8 +363,17 @@ def repartition(problem: PartitionProblem, previous: PartitionResult,
     Args:
         problem: the perturbed instance — same point count (and point
             identity) as ``previous``, typically with drifted weights
-            and/or moved points.
+            and/or moved points; or, with ``identity``, a refined or
+            coarsened point set of another count.
         previous: the ``PartitionResult`` of the last (re)partition call.
+        identity: [problem.n] integers: for each point of ``problem``
+            its index in ``previous``'s point set, or -1 for a point
+            that refinement created (None = the same point set). With a
+            map, the warm solve resumes from the previous centers and
+            influence, never re-emits the previous labels (no-op
+            detection is off), and pads its point count to a bucket
+            (``core.partitioner.warm_slots``) so that steps of similar
+            size share one compiled solve.
         method: registry name. Methods with ``supports_warm_start`` (see
             ``warm_start_methods()``) resume balanced k-means from
             ``previous.centers`` / ``previous.influence``; all others cold
@@ -345,12 +407,17 @@ def repartition(problem: PartitionProblem, previous: PartitionResult,
         ``stats["warm_start"]``, ``stats["iters"]`` (cumulative movement
         iterations; 0 when ``previous`` is still a fixed point) and
         ``stats["migration"]`` = {"volume", "fraction",
-        "retained_fraction"} measured against ``previous`` under the NEW
-        weights.
+        "retained_fraction", "created"} measured against ``previous``
+        under the NEW weights: with ``identity``, volume and fraction
+        are over the persisting points (the fraction of their weight
+        that changed blocks), and ``created`` is the weight of the
+        created points (0.0 without a map).
 
     Raises:
-        ValueError: k/n mismatch with ``previous``, or ``warm=True`` for
-            a method without warm-start support / a previous result
+        ValueError: k mismatch with ``previous``, an n mismatch without
+            ``identity``, an ``identity`` of the wrong shape or with an
+            index outside ``[-1, previous n)``, or ``warm=True`` for a
+            method without warm-start support / a previous result
             without centers.
     """
     if not isinstance(problem, PartitionProblem):
@@ -359,7 +426,7 @@ def repartition(problem: PartitionProblem, previous: PartitionResult,
     with jax.profiler.TraceAnnotation("repro.repartition", method=method,
                                       n=problem.n, k=problem.k,
                                       call=next(_CALLS)):
-        _check_previous(problem, previous)
+        identity = _check_previous(problem, previous, identity)
         name = resolve_method(method)
         can_warm = supports_warm_start(name) and previous.centers is not None
         if warm is None:
@@ -379,15 +446,17 @@ def repartition(problem: PartitionProblem, previous: PartitionResult,
         else:
             refine = None
         if warm:
-            res = _warm_geographer(problem, previous, devices, **opts)
+            res = _warm_geographer(problem, previous, devices,
+                                   identity is not None, **opts)
         else:
-            res = _cold_relabel(problem, previous, name, devices, **opts)
+            res = _cold_relabel(problem, previous, name, devices, identity,
+                                **opts)
         if refine is not None:
             from .refine import refine as _refine
             res = _refine(problem, res, refine, devices=devices,
                           eps=refine_eps)
         res.stats["migration"] = _migration_stats(previous, res.labels,
-                                                  problem.weights)
+                                                  problem.weights, identity)
         if evaluate:
             res.evaluate(with_diameter=with_diameter)
         return res

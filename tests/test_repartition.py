@@ -293,3 +293,173 @@ class TestScanDriver:
             sim = simulate_loadbalance(prob, wl, steps=3, mode="warm")
             assert sim["summary"]["all_balanced"], (name, sim["summary"])
             assert sim["workload"] == type(wl).__name__
+
+
+# ---------------------------------------------------------------------------
+# a point set that changes: refinement and coarsening through identity=
+# ---------------------------------------------------------------------------
+
+def _refined_step(prob: PartitionProblem, grow: bool, seed: int = 5,
+                  weighted: bool = False):
+    """The next step of ``prob``'s mesh: a tenth of the points coarsened
+    away and, when ``grow``, a fifth as many created in a corner. Returns
+    (problem, identity)."""
+    rng = np.random.default_rng(seed)
+    kept = np.sort(rng.choice(prob.n, size=prob.n - prob.n // 10,
+                              replace=False))
+    m = prob.n // 5 if grow else 0
+    pts = np.concatenate([prob.points[kept], rng.uniform(0, 0.4, (m, 2))])
+    identity = np.concatenate([kept, np.full(m, -1)])
+    w = rng.uniform(0.5, 2.0, pts.shape[0]) if weighted else None
+    return (PartitionProblem(points=pts, k=prob.k, weights=w, epsilon=EPS,
+                             seed=seed), identity)
+
+
+class TestChangingPointSet:
+    @pytest.fixture(scope="class")
+    def prev(self):
+        prob = _hotspot_problem(n=2000, k=8, seed=4)
+        return prob, partition(prob, method="geographer")
+
+    @pytest.mark.parametrize("grow", [True, False], ids=["grow", "shrink"])
+    def test_step_from_previous_centers(self, prev, grow, monkeypatch):
+        import importlib
+        rp = importlib.import_module("repro.partition.repartition")
+        prob0, res0 = prev
+        prob, identity = _refined_step(prob0, grow)
+        assert prob.n != prob0.n
+        seen = []
+        orig = rp.geographer_repartition
+
+        def spy(points, k, centers0, influence0, *a, **kw):
+            seen.append((np.array(centers0), np.array(influence0)))
+            return orig(points, k, centers0, influence0, *a, **kw)
+        monkeypatch.setattr(rp, "geographer_repartition", spy)
+        res = repartition(prob, res0, identity=identity)
+        assert res.labels.shape == (prob.n,)
+        assert res.labels.min() >= 0 and res.labels.max() < prob.k
+        assert res.imbalance() <= EPS + 1e-6
+        assert res.stats["warm_start"] is True
+        assert np.array_equal(seen[0][0], res0.centers)
+        assert np.array_equal(seen[0][1], res0.influence)
+
+    @pytest.mark.parametrize("weighted", [False, True],
+                             ids=["unit", "weighted"])
+    def test_migration_recount(self, prev, weighted):
+        prob0, res0 = prev
+        prob, identity = _refined_step(prob0, True, weighted=weighted)
+        res = repartition(prob, res0, identity=identity)
+        w = (np.ones(prob.n) if prob.weights is None
+             else np.asarray(prob.weights, np.float64))
+        moved = kept_w = created = 0.0
+        for i in range(prob.n):                 # the plain recount
+            if identity[i] < 0:
+                created += w[i]
+                continue
+            kept_w += w[i]
+            if res.labels[i] != res0.labels[identity[i]]:
+                moved += w[i]
+        mig = res.stats["migration"]
+        assert mig["created"] == pytest.approx(created, rel=1e-12)
+        assert mig["volume"] == pytest.approx(moved, rel=1e-12)
+        assert mig["fraction"] == pytest.approx(moved / kept_w, rel=1e-12)
+        assert mig["retained_fraction"] == pytest.approx(1 - moved / kept_w)
+        assert 0 < mig["volume"] < kept_w
+
+    def test_same_point_set_creates_nothing(self, prev):
+        prob0, res0 = prev
+        assert repartition(prob0, res0).stats["migration"]["created"] == 0.0
+
+    def test_one_bucket_compiles_once(self):
+        """Five growing steps whose point counts share a padding bucket
+        trace and compile the warm solve on the first step only."""
+        import jax
+        import jax.monitoring
+
+        from repro.core.partitioner import warm_slots
+        events = {"/jax/core/compile/jaxpr_trace_duration": "traces",
+                  "/jax/core/compile/backend_compile_duration": "compiles"}
+        counts = {"traces": 0, "compiles": 0}
+
+        def on(event, duration, **_):
+            if event in events:
+                counts[events[event]] += 1
+        rng = np.random.default_rng(8)
+        pts = rng.uniform(0, 1, (2090, 2))
+        res = partition(PartitionProblem(points=pts, k=8, epsilon=EPS,
+                                         seed=8))
+        steps = []
+        jax.clear_caches()                 # no solve compiled by another test
+        jax.monitoring.register_event_duration_secs_listener(on)
+        try:
+            for s in range(5):
+                new = rng.uniform(0.3, 0.7, (30, 2))
+                identity = np.concatenate([np.arange(len(pts)),
+                                           np.full(30, -1)])
+                pts = np.concatenate([pts, new])
+                before = dict(counts)
+                res = repartition(PartitionProblem(points=pts, k=8,
+                                                   epsilon=EPS, seed=s),
+                                  res, identity=identity)
+                steps.append({k: counts[k] - before[k] for k in counts})
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on)
+        assert len({warm_slots(2090 + 30 * (s + 1)) for s in range(5)}) == 1
+        assert steps[0]["compiles"] >= 1
+        assert steps[1:] == [{"traces": 0, "compiles": 0}] * 4
+
+    def test_padded_solve_matches_unpadded(self, prev):
+        """Pad slots carry weight zero: the labels are the unpadded
+        solve's, the centers agree to float32 summation order (1e-5 of
+        the unit square)."""
+        from repro.core.partitioner import geographer_repartition, warm_slots
+        prob0, res0 = prev
+        prob, _ = _refined_step(prob0, True)
+        assert warm_slots(prob.n) > prob.n
+        cfg = BKMConfig(k=prob.k, epsilon=EPS, warmup=False,
+                        delta_tol=WARM_DELTA_TOL)
+        runs = [geographer_repartition(
+            prob.points, prob.k, res0.centers, res0.influence, cfg=cfg,
+            seed=prob.seed, pad=pad) for pad in (False, True)]
+        (la, ca, ia, sa), (lb, cb, ib, sb) = runs
+        assert np.array_equal(la, lb)
+        assert int(sa["iters"]) == int(sb["iters"])
+        np.testing.assert_allclose(cb, ca, rtol=0, atol=1e-5)
+        np.testing.assert_allclose(ib, ia, rtol=1e-5)
+        assert float(sb["skip_fraction_final"]) == pytest.approx(
+            float(sa["skip_fraction_final"]), abs=1e-6)
+
+    @pytest.mark.parametrize("grow", [True, False], ids=["grow", "shrink"])
+    def test_devices1_bit_for_bit(self, prev, grow):
+        prob0, res0 = prev
+        prob, identity = _refined_step(prob0, grow)
+        single = repartition(prob, res0, identity=identity)
+        d1 = repartition(prob, res0, identity=identity, devices=1)
+        assert np.array_equal(single.labels, d1.labels)
+        assert np.array_equal(single.centers, d1.centers)
+        assert np.array_equal(single.influence, d1.influence)
+        assert single.stats["iters"] == d1.stats["iters"]
+        assert single.stats["migration"] == d1.stats["migration"]
+
+    @pytest.mark.parametrize("bad", ["short", "float", "below", "above"])
+    def test_bad_identity_rejected(self, prev, bad):
+        prob0, res0 = prev
+        prob, identity = _refined_step(prob0, True)
+        identity = {"short": identity[:-1],
+                    "float": identity.astype(np.float64),
+                    "below": np.where(identity < 0, -2, identity),
+                    "above": np.where(identity < 0, prob0.n, identity)}[bad]
+        with pytest.raises(ValueError, match="identity"):
+            repartition(prob, res0, identity=identity)
+
+    def test_cold_relabel_over_persisting_points(self, prev):
+        """A centerless method cold-starts and is matched to the previous
+        blocks' centroids over the persisting points."""
+        prob0, _ = prev
+        rcb0 = partition(prob0, method="rcb")
+        prob, identity = _refined_step(prob0, True)
+        res = repartition(prob, rcb0, method="rcb", identity=identity)
+        assert res.stats["relabel_matched"] is True
+        assert res.labels.shape == (prob.n,)
+        assert res.stats["migration"]["created"] == float(
+            np.sum(identity < 0))
