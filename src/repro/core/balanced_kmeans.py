@@ -65,7 +65,7 @@ class BKMConfig:
     backend: str = "auto"          # kernels.ops assign backend
     use_kernel: bool = False       # deprecated: alias for backend="pallas"
     fused: bool | None = None      # fused assign+reduce; None = auto
-    block_p: int = 1024            # kernel point-tile
+    block_p: int = 8192            # kernel point-tile (lanes)
     block_c: int = 128             # kernel center-tile
     assign_chunk: int | None = None  # jnp path point chunk; None = adaptive
     assign_precision: str = "f32"  # distance matmul: "f32" | "bf16"
@@ -109,7 +109,7 @@ def _reduce(x, axis_name, op="sum"):
 
 
 def assign_effective(points, centers, influence, chunk=None, backend="auto",
-                     block_p=1024, block_c=128, precision="f32"):
+                     block_p=8192, block_c=128, precision="f32"):
     """Returns (assignment [n] int32, best_eff [n], second_eff [n]) where
     best/second are *true* effective distances dist/influence.
 

@@ -3,55 +3,70 @@
 Computes, for every point p, the cluster c minimizing
 ``sqdist(p, c) / influence(c)^2`` together with the best and second-best
 effective squared distances (needed for the Hamerly bounds, Eqs. 4-5).
-With ``with_moments=True`` the same pass also accumulates the per-cluster
-weighted moments (Alg. 2's movement reductions) in a VMEM block revisited
-across point tiles, so the point array is streamed exactly once.
+``assign_reduce_pallas`` also accumulates the per-cluster weighted moments
+(Alg. 2's movement reductions) in a VMEM block revisited across point
+tiles, so the point array is streamed exactly once.
 
-TPU adaptation of the paper's geometric optimizations (DESIGN.md §4):
+The tile is lane-dense (DESIGN.md §4c): points lie on the 128-lane axis,
+centers on sublanes.
 
-* The pairwise-distance inner loop becomes an MXU matmul per
-  (point-tile × center-tile): ``sq = |p|^2 + |c|^2 - 2 p @ c^T``.
+* Points enter as ``[d, N]`` in ``(d, BP)`` blocks, centers as ``[K, d]``
+  in ``(BC, d)`` blocks, ``1/influence^2`` as a ``[K, 1]`` column. Each
+  grid step walks its point tile in lane chunks of ``L`` points and forms
+  the ``[BC, L]`` tile of effective distances, one center per sublane.
+* The reductions over centers run down sublanes: an elementwise ``min``
+  across vregs, then one 8-way sublane fold. The index is the first row
+  attaining the minimum (min, exact equality, max over the reversed row
+  iota: ``jnp.argmin``'s tie rule, as ``ops._chunk_assign``), and the
+  second-best masks that row. Results are ``[1, L]`` lane rows, stored
+  into the ``[1, N]`` idx/best/second outputs with no relayout.
+* The cross term ``p·c`` follows the static ``d`` and ``precision``.
+  With ``precision="f32"`` and ``d <= _VPU_MAX_D`` (the meshes' 2 and 3
+  coordinates) it is d broadcast multiply-adds on the VPU, f32
+  throughout, in the oracle's expression ``|p|^2 + |c|^2 - 2 p·c``: a
+  matmul would fill d of its 128 contraction rows and need 6 bf16 passes
+  for f32. Otherwise (wider points, or ``precision="bf16"``) it is one
+  MXU ``dot_general`` of the ``[BC, d]`` centers against the ``[d, L]``
+  points, ``Precision.HIGHEST`` in f32 mode and bf16 operands with f32
+  accumulation in bf16 mode; the norms, the running best/second and the
+  moments stay f32 either way (tolerances in DESIGN.md §4c).
 * The paper's per-point Hamerly branch and bounding-box center ordering
   become **tile-level pruning**: the wrapper (ops.py) precomputes a lower
-  bound on the effective sqdist between each point-tile's bounding box and
-  each center-tile; inside the kernel a whole center-tile is skipped via
-  ``pl.when`` when its bound cannot beat the tile's current worst
-  second-best. Centers are pre-sorted by distance to the local bounding box
-  (paper Alg. 1 line 6) so prunable tiles appear late in the ``arbitrary``
-  grid dimension.
+  bound on the effective sqdist between each point tile's bounding box
+  and each center tile; a whole center tile is skipped via ``pl.when``
+  when its bound cannot beat the tile's current worst second-best. With
+  more than one center tile the centers are pre-sorted by distance to the
+  global bounding box (paper Alg. 1 line 6) so prunable tiles appear late
+  in the ``arbitrary`` grid dimension.
 * Padded centers (the ``_FAR`` rows the wrapper appends to reach a
-  ``block_c`` multiple) are masked to ``+inf`` effective distance by the
-  static real-center count ``k_real`` — the distance math itself is never
-  trusted for them (``|FAR|^2`` overflows f32 and can turn into NaN via
-  ``inf - inf`` for large-coordinate inputs, which used to corrupt both
-  the argmin and the second-best).
+  center-tile multiple) are masked to ``+inf`` effective distance by the
+  static real-center count ``k_real``: the distance math is never trusted
+  for them (``|FAR|^2`` overflows f32 and ``inf - inf`` is NaN).
 * Running (best, second, argmin) accumulators live in the output VMEM
-  blocks, revisited across the center-tile grid dimension. In moments mode
+  rows, revisited across the center-tile grid dimension. In moments mode
   the ``[d+2, K]`` moment block (csum rows, weight row, radius row) is
-  revisited across the *point*-tile dimension as well: each point tile
-  adds its one-hot-matmul partial after its last center tile, so both grid
-  dimensions become ``arbitrary`` (sequential) to keep the accumulation
-  well-defined.
-* Every per-point vector (weights in, idx/best/second out) is a ``[1, N]``
-  row with ``(1, block_p)`` blocks, and the per-tile prune bounds are
-  read as SMEM scalars. Both keep every block legal for Mosaic's
-  (8, 128) tiling rule, also under ``vmap`` (the batched hierarchical
-  refinement), where a 1-D ``(block_p,)`` block would become an illegal
-  ``(1, block_p)`` slice of a ``[B, N]`` array.
-* ``precision="bf16"`` computes the ``p @ c^T`` cross term on the MXU in
-  bf16 (f32 accumulation); the norms ``|p|^2``/``|c|^2``, the Hamerly
-  best/second accumulators and the moment block stay f32. Tolerance
-  bounds documented in DESIGN.md §4c.
+  revisited across the point-tile dimension as well: after its last
+  center tile each point tile adds ``[d+2, L] x [K, L]^T`` matmuls of the
+  stacked coordinates against the weighted one-hot of its final labels,
+  both operands contracting their lane axis, so both grid dimensions are
+  ``arbitrary`` (sequential).
+* The prune bounds are read as SMEM scalars. Every block's last two
+  dimensions divide by (8, 128) or equal the array's, also under ``vmap``
+  (the batched hierarchical refinement adds a leading batch dimension).
 
-Grid: ``(n_point_tiles, n_center_tiles)``. VMEM per step: BP*D + BC*D +
-BP*BC floats (+ 3 BP-sized accumulators, + BP + (d+2)*K + BP*K in moments
-mode) — e.g. BP=1024, BC=128, D<=128, K=1024 → ~5.5 MB, under the
-~16 MB v5e VMEM budget, with BP*BC = 1024x128 matching MXU tiling
-(multiples of 128 on the lane dimension).
+Grid: ``(N/BP, K/BC)``. VMEM per step: the point tile and the four
+``[1, BP]`` rows, each padded to 8 sublanes and double-buffered
+(2·8·BP·(1 + 4) floats), the center tile, and a few ``[BC, L]`` (moments:
+``[K, L]``) f32 temporaries; e.g. BP = 8192, BC = 64, L = 2048:
+2.5 MiB + 512 KiB per temporary, inside the 16 MiB scoped VMEM of a v5e.
+Larger chunks amortise the loop: on a TPU v5e at n = 5,824,554, k = 64,
+d = 2, one fused sweep (wrapper and kernel) took 7.4 ms with 32K-float
+temporaries and 5.9 ms with 128K.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +74,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 PRECISIONS = ("f32", "bf16")
+
+_VPU_MAX_D = 4          # f32 cross term on the VPU up to this many coords
+_CHUNK_ELEMS = 1 << 17  # f32 elements of one [rows, L] temporary (512 KiB)
 
 
 def _check_tiling(n: int, k: int, block_p: int, block_c: int,
@@ -79,7 +97,8 @@ def _check_tiling(n: int, k: int, block_p: int, block_c: int,
 
 
 def _cross_term(p, c, precision: str):
-    """-2 p @ c^T cross term of the squared distance, [BP, BC] f32.
+    """-2 p @ c^T cross term of the squared distance, [BP, BC] f32, for
+    points on sublanes (the triton-shaped backend's ``[BP, d]`` tile).
 
     ``f32`` asks for full f32 contraction (``Precision.HIGHEST``): the
     TPU's default f32 matmul rounds its operands to bfloat16, which the
@@ -87,23 +106,52 @@ def _cross_term(p, c, precision: str):
     the MXU matmul (accumulation stays f32 via ``preferred_element_type``):
     half the operand bandwidth and double the MXU rate on TPU, at a
     relative distance error bounded by ~2^-8 per coordinate product."""
+    return _mxu_dot(p, c, ((1,), (1,)), precision)
+
+
+def _mxu_dot(a, b, contract, precision: str):
+    """``dot_general`` over the ``contract`` axes with f32 accumulation:
+    ``Precision.HIGHEST`` in f32 mode, bf16 operands in bf16 mode."""
     if precision == "bf16":
-        p = p.astype(jnp.bfloat16)
-        c = c.astype(jnp.bfloat16)
+        a = a.astype(jnp.bfloat16)
+        b = b.astype(jnp.bfloat16)
         exact = None
     else:
         exact = jax.lax.Precision.HIGHEST
-    return jax.lax.dot_general(p, c, (((1,), (1,)), ((), ())),
-                               precision=exact,
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=exact,
                                preferred_element_type=jnp.float32)
 
 
-def _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
-                 second_ref, *, block_c: int, k_real: int, precision: str):
+def _lane_cross(c, p, precision: str):
+    """p·c of every (center, point) pair of the lane-dense tile, [BC, L]
+    f32, from centers [BC, d] and points [d, L]: VPU multiply-adds for f32
+    at small d, else one MXU matmul (see the module docstring)."""
+    d = p.shape[0]
+    if precision == "f32" and d <= _VPU_MAX_D:
+        acc = c[:, 0:1] * p[0:1, :]
+        for a in range(1, d):
+            acc = acc + c[:, a:a + 1] * p[a:a + 1, :]
+        return acc
+    return _mxu_dot(c, p, ((1,), (0,)), precision)
+
+
+def _lanes(rows: int, block_p: int) -> int:
+    """Lane chunk for ``[rows, L]`` temporaries of about ``_CHUNK_ELEMS``
+    floats: a power of two from 128 up, dividing the point tile."""
+    target = max(128, 1 << ((_CHUNK_ELEMS // rows).bit_length() - 1))
+    return math.gcd(block_p, target)
+
+
+def _assign_step(points_ref, bounds_ref, centers_ref, inv2_ref, idx_ref,
+                 best_ref, second_ref, *, k_real: int, masked: bool,
+                 precision: str, lanes: int):
     """One (point-tile × center-tile) grid step: init at the first center
-    tile, tile-level bbox pruning, distance matmul + running
-    (best, second, argmin) update in the ``[1, BP]`` output rows."""
+    tile, tile-level bbox pruning, then per chunk of ``lanes`` points the
+    ``[BC, L]`` effective distances and the running (best, second,
+    argmin) update in the ``[1, BP]`` output rows."""
     j = pl.program_id(1)
+    block_c = centers_ref.shape[0]
+    block_p = points_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -118,48 +166,58 @@ def _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
 
     @pl.when((j == 0) | (bound < worst_second))
     def _compute():
-        c = centers_ref[...]                   # [BC, D]
-        inv2 = inv2_ref[...]                   # [1, BC]
-        pn = jnp.sum(p * p, axis=1, keepdims=True)          # [BP, 1]
-        cn = jnp.sum(c * c, axis=1)[None, :]                # [1, BC]
-        sq = pn + cn - 2.0 * _cross_term(p, c, precision)   # [BP, BC]
-        eff = jnp.maximum(sq, 0.0) * inv2                   # [BP, BC]
+        c = centers_ref[...]                                 # [BC, D]
+        cn = jnp.sum(c * c, axis=1, keepdims=True)           # [BC, 1]
+        inv2 = inv2_ref[...]                                 # [BC, 1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_c, 1), 0)
         # mask padded (_FAR) centers to +inf: their f32 distance overflows
         # (or NaNs via inf - inf) and must never reach argmin/second
-        cols = j * block_c + jax.lax.broadcasted_iota(
-            jnp.int32, eff.shape, 1)
-        eff = jnp.where(cols < k_real, eff, jnp.inf)
+        real = rows < k_real - j * block_c                   # [BC, 1]
+        rev = block_c - rows
 
-        local_idx = jnp.argmin(eff, axis=1).astype(jnp.int32)
-        local_best = jnp.min(eff, axis=1)
-        bc = eff.shape[1]
-        onehot = jax.nn.one_hot(local_idx, bc, dtype=jnp.bool_)
-        local_second = jnp.min(jnp.where(onehot, jnp.inf, eff), axis=1)
+        def chunk(s, carry):
+            cols = pl.ds(pl.multiple_of(s * lanes, lanes), lanes)
+            p = points_ref[:, cols]                          # [D, L]
+            pn = jnp.sum(p * p, axis=0, keepdims=True)       # [1, L]
+            sq = (pn + cn) - 2.0 * _lane_cross(c, p, precision)
+            eff = jnp.maximum(sq, 0.0) * inv2                # [BC, L]
+            if masked:
+                eff = jnp.where(real, eff, jnp.inf)
+            best = jnp.min(eff, axis=0, keepdims=True)       # [1, L]
+            first = jnp.max(jnp.where(eff == best, rev, 0), axis=0,
+                            keepdims=True)
+            idx = block_c - first                            # [1, L]
+            second = jnp.min(jnp.where(rows == idx, jnp.inf, eff), axis=0,
+                             keepdims=True)
 
-        old_best = best_ref[0]
-        old_second = second_ref[0]
-        old_idx = idx_ref[0]
-        take_new = local_best < old_best
-        new_best = jnp.where(take_new, local_best, old_best)
-        new_second = jnp.minimum(
-            jnp.minimum(old_second, local_second),
-            jnp.maximum(old_best, local_best))
-        new_idx = jnp.where(take_new, j * block_c + local_idx, old_idx)
-        best_ref[0] = new_best
-        second_ref[0] = new_second
-        idx_ref[0] = new_idx
+            old_best = best_ref[:, cols]
+            old_second = second_ref[:, cols]
+            take_new = best < old_best
+            best_ref[:, cols] = jnp.where(take_new, best, old_best)
+            second_ref[:, cols] = jnp.minimum(
+                jnp.minimum(old_second, second),
+                jnp.maximum(old_best, best))
+            idx_ref[:, cols] = jnp.where(take_new, j * block_c + idx,
+                                         idx_ref[:, cols])
+            return carry
+
+        jax.lax.fori_loop(0, block_p // lanes, chunk, 0)
 
 
-def _moments_step(p, w_ref, idx_ref, best_ref, moments_ref):
+def _moments_step(points_ref, w_ref, idx_ref, best_ref, moments_ref, *,
+                  lanes: int):
     """Moment accumulation into the grid-wide ``[d+2, K]`` VMEM block:
     rows ``0..d-1`` hold the weighted coordinate sums, row ``d`` the
     weighted counts, row ``d+1`` the weighted best effective-sq distances
     — all in *sorted-center* column space (the wrapper un-sorts). Each
-    point tile contributes its one-hot matmul partial once, after its
+    point tile contributes its one-hot matmul partials once, after its
     final center tile. Accumulation is always f32, independent of the
-    distance-matmul precision."""
+    distance precision."""
     i = pl.program_id(0)
     j = pl.program_id(1)
+    d = points_ref.shape[0]
+    block_p = points_ref.shape[1]
+    kpad = moments_ref.shape[1]
 
     @pl.when((i == 0) & (j == 0))
     def _zero():
@@ -167,39 +225,36 @@ def _moments_step(p, w_ref, idx_ref, best_ref, moments_ref):
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _accumulate():
-        w = w_ref[0]                                         # [BP]
-        idx = idx_ref[0]                                     # [BP]
-        best = best_ref[0]                                   # [BP]
-        kpad = moments_ref.shape[1]
-        onehot = idx[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (p.shape[0], kpad), 1)                # [BP, K]
-        ww = jnp.where(onehot, w[:, None], 0.0)              # [BP, K]
-        stacked = jnp.concatenate(
-            [p, jnp.ones((p.shape[0], 1), p.dtype), best[:, None]],
-            axis=1)                                          # [BP, D+2]
-        moments_ref[...] += jax.lax.dot_general(
-            stacked, ww, (((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)              # [D+2, K]
+        centers = jax.lax.broadcasted_iota(jnp.int32, (kpad, 1), 0)
+
+        def chunk(s, acc):
+            cols = pl.ds(pl.multiple_of(s * lanes, lanes), lanes)
+            ww = jnp.where(centers == idx_ref[:, cols], w_ref[:, cols],
+                           0.0)                              # [K, L]
+            stacked = jnp.concatenate(
+                [points_ref[:, cols], jnp.ones((1, lanes), jnp.float32),
+                 best_ref[:, cols]], axis=0)                 # [D+2, L]
+            return acc + _mxu_dot(stacked, ww, ((1,), (1,)),
+                                  "f32")                     # [D+2, K]
+
+        moments_ref[...] += jax.lax.fori_loop(
+            0, block_p // lanes, chunk,
+            jnp.zeros((d + 2, kpad), jnp.float32))
 
 
 def _assign_kernel(bounds_ref, points_ref, centers_ref, inv2_ref,
-                   idx_ref, best_ref, second_ref, *, block_c: int,
-                   k_real: int, precision: str):
-    _assign_step(points_ref[...], bounds_ref, centers_ref, inv2_ref,
-                 idx_ref, best_ref, second_ref, block_c=block_c,
-                 k_real=k_real, precision=precision)
+                   idx_ref, best_ref, second_ref, **step):
+    _assign_step(points_ref, bounds_ref, centers_ref, inv2_ref, idx_ref,
+                 best_ref, second_ref, **step)
 
 
 def _assign_moments_kernel(bounds_ref, points_ref, centers_ref, inv2_ref,
                            w_ref, idx_ref, best_ref, second_ref,
-                           moments_ref, *, block_c: int, k_real: int,
-                           precision: str):
-    p = points_ref[...]
-    _assign_step(p, bounds_ref, centers_ref, inv2_ref, idx_ref, best_ref,
-                 second_ref, block_c=block_c, k_real=k_real,
-                 precision=precision)
-    _moments_step(p, w_ref, idx_ref, best_ref, moments_ref)
+                           moments_ref, *, moment_lanes: int, **step):
+    _assign_step(points_ref, bounds_ref, centers_ref, inv2_ref, idx_ref,
+                 best_ref, second_ref, **step)
+    _moments_step(points_ref, w_ref, idx_ref, best_ref, moments_ref,
+                  lanes=moment_lanes)
 
 
 def default_interpret() -> bool:
@@ -211,17 +266,24 @@ def default_interpret() -> bool:
 def _specs(n: int, d: int, k: int, block_p: int, block_c: int):
     """Grid and the blocks shared by both kernels: the point tile's row of
     prune bounds in SMEM (``[N/BP, 1, K/BC]``, one ``(1, K/BC)`` block per
-    point tile, so SMEM use grows with K only), point/center tiles, the
-    ``[1, K]`` inverse-influence row, and the ``[1, N]`` per-point rows
-    (weights, idx, best, second)."""
+    point tile, so SMEM use grows with K only), the ``(d, BP)`` point tile,
+    the ``(BC, d)`` center tile, the ``(BC, 1)`` inverse-influence column,
+    and the ``[1, N]`` per-point rows (weights, idx, best, second)."""
     grid = (n // block_p, k // block_c)
     bounds = pl.BlockSpec((None, 1, grid[1]), lambda i, j: (i, 0, 0),
                           memory_space=pltpu.SMEM)
-    points = pl.BlockSpec((block_p, d), lambda i, j: (i, 0))
+    points = pl.BlockSpec((d, block_p), lambda i, j: (0, i))
     centers = pl.BlockSpec((block_c, d), lambda i, j: (j, 0))
-    inv2 = pl.BlockSpec((1, block_c), lambda i, j: (0, j))
+    inv2 = pl.BlockSpec((block_c, 1), lambda i, j: (j, 0))
     row = pl.BlockSpec((1, block_p), lambda i, j: (0, i))
     return grid, [bounds, points, centers, inv2], row
+
+
+def _step_args(k: int, k_real: int, block_p: int, block_c: int,
+               precision: str) -> dict:
+    """Static arguments of ``_assign_step``."""
+    return dict(k_real=k_real, masked=k_real < k, precision=precision,
+                lanes=_lanes(block_c, block_p))
 
 
 def _row_shapes(n: int):
@@ -234,24 +296,26 @@ def _row_shapes(n: int):
                    static_argnames=("k_real", "block_p", "block_c",
                                     "interpret", "precision"))
 def assign_argmin_pallas(points, centers, inv2, tile_bounds, k_real: int,
-                         block_p: int = 1024, block_c: int = 128,
+                         block_p: int = 8192, block_c: int = 128,
                          interpret: bool | None = None,
                          precision: str = "f32"):
-    """points [N, D], centers [K, D] (pre-padded), inv2 [K] = 1/influence^2,
-    tile_bounds [N/BP, K/BC], k_real = number of real (non-_FAR) centers.
-    Returns (idx, best_eff_sq, second_eff_sq), each [N].
+    """points [D, N] (lane-dense), centers [K, D] (pre-padded),
+    inv2 [K] = 1/influence^2, tile_bounds [N/BP, K/BC], k_real = number of
+    real (non-_FAR) centers. Returns (idx, best_eff_sq, second_eff_sq),
+    each [N].
 
     ``interpret=None`` auto-detects: compiled on TPU, interpret elsewhere.
     Pass an explicit bool to override (e.g. interpret-mode debugging on
-    TPU hosts). ``precision`` is the distance-matmul mode ("f32"/"bf16")."""
+    TPU hosts). ``precision`` is the cross-term mode ("f32"/"bf16")."""
     if interpret is None:
         interpret = default_interpret()
-    n, d = points.shape
+    d, n = points.shape
     k = centers.shape[0]
     _check_tiling(n, k, block_p, block_c, "assign_argmin_pallas")
     grid, in_specs, row = _specs(n, d, k, block_p, block_c)
-    kernel = functools.partial(_assign_kernel, block_c=block_c,
-                               k_real=k_real, precision=precision)
+    kernel = functools.partial(
+        _assign_kernel,
+        **_step_args(k, k_real, block_p, block_c, precision))
     idx, best, second = pl.pallas_call(
         kernel,
         grid=grid,
@@ -261,7 +325,7 @@ def assign_argmin_pallas(points, centers, inv2, tile_bounds, k_real: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tile_bounds[:, None, :], points, centers, inv2[None, :])
+    )(tile_bounds[:, None, :], points, centers, inv2[:, None])
     return idx[0], best[0], second[0]
 
 
@@ -269,7 +333,7 @@ def assign_argmin_pallas(points, centers, inv2, tile_bounds, k_real: int,
                    static_argnames=("k_real", "block_p", "block_c",
                                     "interpret", "precision"))
 def assign_reduce_pallas(points, centers, inv2, tile_bounds, weights,
-                         k_real: int, block_p: int = 1024,
+                         k_real: int, block_p: int = 8192,
                          block_c: int = 128,
                          interpret: bool | None = None,
                          precision: str = "f32"):
@@ -281,12 +345,13 @@ def assign_reduce_pallas(points, centers, inv2, tile_bounds, weights,
     ``weights [N]`` (zero marks padded points)."""
     if interpret is None:
         interpret = default_interpret()
-    n, d = points.shape
+    d, n = points.shape
     k = centers.shape[0]
     _check_tiling(n, k, block_p, block_c, "assign_reduce_pallas")
     grid, in_specs, row = _specs(n, d, k, block_p, block_c)
-    kernel = functools.partial(_assign_moments_kernel, block_c=block_c,
-                               k_real=k_real, precision=precision)
+    kernel = functools.partial(
+        _assign_moments_kernel, moment_lanes=_lanes(k, block_p),
+        **_step_args(k, k_real, block_p, block_c, precision))
     idx, best, second, moments = pl.pallas_call(
         kernel,
         grid=grid,
@@ -300,6 +365,6 @@ def assign_reduce_pallas(points, centers, inv2, tile_bounds, weights,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_bounds[:, None, :], points, centers, inv2[None, :],
+    )(tile_bounds[:, None, :], points, centers, inv2[:, None],
       weights[None, :])
     return idx[0], best[0], second[0], moments

@@ -139,9 +139,8 @@ def resolve_assign_backend(name: str = "auto", *, sharded: bool = False,
     distributed partitioner): the choice is pinned *before* tracing —
     ``jax.default_backend()`` is process-global, not trace-local — and
     because the Pallas kernel's tile pruning only pays off once the local
-    shard spans at least one full point tile, shards smaller than
-    ``n_local < 1024`` (the default ``block_p``) resolve to the chunked
-    jnp path even on TPU hosts.
+    shard spans whole point tiles, shards of ``n_local < 1024`` points
+    resolve to the chunked jnp path even on TPU hosts.
     """
     if name == "auto":
         env = os.environ.get("REPRO_ASSIGN_BACKEND")
@@ -318,14 +317,25 @@ def assign_argmin_jnp(points, centers, influence, *,
             + _split_moments(m.sum(axis=0), d))
 
 
-def _tile_bounds(points, centers, inv2, block_p, block_c):
-    """Lower bound of effective sqdist between each point-tile's bbox and
-    each center tile: max(0, bbox-distance)^2 * max tile inv2."""
-    n, d = points.shape
+def kernel_tiles(n: int, k: int, block_p: int, block_c: int):
+    """The assign kernel's (point tile, center tile) for n points and k
+    centers: ``block_p`` rounded up to whole 128-lane vregs and cut to the
+    point count so rounded, ``block_c`` cut to k rounded up to 8 sublanes
+    (k = 64 runs one 64-row tile, not a half-padded 128-row one)."""
+    bp = min(-(-block_p // 128) * 128, -(-n // 128) * 128)
+    bc = min(block_c, -(-k // 8) * 8)
+    return bp, bc
+
+
+def _tile_bounds(pts, centers, inv2, block_p, block_c):
+    """Lower bound of effective sqdist between each point tile's bbox and
+    each center tile: max(0, bbox-distance)^2 * max tile inv2. ``pts`` is
+    the kernel's lane-dense ``[d, N]`` copy."""
+    d, n = pts.shape
     k = centers.shape[0]
-    pt = points.reshape(n // block_p, block_p, d)
-    lo = jnp.min(pt, axis=1)                       # [nPT, d]
-    hi = jnp.max(pt, axis=1)
+    pt = pts.reshape(d, n // block_p, block_p)
+    lo = jnp.min(pt, axis=2).T                     # [nPT, d]
+    hi = jnp.max(pt, axis=2).T
     ct = centers.reshape(k // block_c, block_c, d)  # [nCT, BC, d]
     # distance of each center to each tile bbox
     cexp = ct[None]                                 # [1, nCT, BC, d]
@@ -337,9 +347,44 @@ def _tile_bounds(points, centers, inv2, block_p, block_c):
     return jnp.min(eff, axis=-1)                    # [nPT, nCT]
 
 
+def _stage(points, centers, influence, block_p, block_c):
+    """The kernel's operands, once a sweep: the points transposed to a
+    lane-dense ``[d, N_pad]`` copy (edge-padded, so tile bboxes stay
+    tight), centers and ``inv2`` padded to the center tile, and the
+    per-tile prune bounds. With more than one center tile the centers are
+    sorted by effective distance to the global point bbox so prunable
+    tiles come late in the sequential grid dimension (``order`` maps a
+    sorted column to its center); one tile has nothing to prune or
+    order, so it gets neither (``order`` is None)."""
+    n, d = points.shape
+    k = centers.shape[0]
+    bp, bc = kernel_tiles(n, k, block_p, block_c)
+    inv2 = (1.0 / (influence * influence)).astype(jnp.float32)
+    centers = centers.astype(jnp.float32)
+    pts = jnp.pad(points.astype(jnp.float32).T, ((0, 0), (0, (-n) % bp)),
+                  mode="edge")
+    n_ct = -(-k // bc)
+    order = None
+    if n_ct > 1:
+        lo = jnp.min(pts, axis=1)
+        hi = jnp.max(pts, axis=1)
+        gap = jnp.maximum(jnp.maximum(lo[None] - centers, centers - hi[None]),
+                          0.0)
+        order = jnp.argsort(jnp.sum(gap * gap, axis=1) * inv2)
+        centers, inv2 = centers[order], inv2[order]
+    pad_k = n_ct * bc - k
+    cts = jnp.pad(centers, ((0, pad_k), (0, 0)), constant_values=_FAR)
+    iv2 = jnp.pad(inv2, (0, pad_k), constant_values=1.0)
+    if n_ct > 1:
+        bounds = _tile_bounds(pts, cts, iv2, bp, bc)
+    else:
+        bounds = jnp.zeros((pts.shape[1] // bp, 1), jnp.float32)
+    return pts, cts, iv2, bounds, order, bp, bc
+
+
 @functools.partial(jax.jit, static_argnames=("block_p", "block_c",
                                              "return_moments", "precision"))
-def assign_argmin(points, centers, influence, block_p: int = 1024,
+def assign_argmin(points, centers, influence, block_p: int = 8192,
                   block_c: int = 128, weights=None,
                   return_moments: bool = False, precision: str = "f32"):
     """Drop-in replacement for ref.assign_argmin_ref (same returns).
@@ -347,60 +392,43 @@ def assign_argmin(points, centers, influence, block_p: int = 1024,
     ``return_moments=True`` (requires ``weights``) runs the fused
     assign+reduce kernel: the per-cluster weighted moments are accumulated
     in VMEM across point tiles and un-sorted back to original center ids
-    here, so the [n, d] point array is streamed exactly once.
-    ``precision`` passes through to the kernel (DESIGN.md §4c: bf16
-    distance matmul). The kernel runs compiled on a TPU and interpreted
+    here, so the point array is streamed exactly once. The tiles come
+    from ``kernel_tiles``. ``precision`` passes through to the kernel
+    (DESIGN.md §4c). The kernel runs compiled on a TPU and interpreted
     elsewhere (``assign_kernel.default_interpret``).
     """
     n, d = points.shape
     k = centers.shape[0]
-    inv2 = 1.0 / (influence * influence)
-
-    # sort centers by effective distance to the global point bbox so that
-    # prunable center tiles appear late in the sequential grid dimension
-    lo = jnp.min(points, axis=0)
-    hi = jnp.max(points, axis=0)
-    gap = jnp.maximum(jnp.maximum(lo[None] - centers, centers - hi[None]), 0.0)
-    key = jnp.sum(gap * gap, axis=1) * inv2
-    order = jnp.argsort(key)
-    centers_s = centers[order]
-    inv2_s = inv2[order]
-
-    pad_n = (-n) % block_p
-    pad_k = (-k) % block_c
-    pts = jnp.pad(points, ((0, pad_n), (0, 0))).astype(jnp.float32)
-    cts = jnp.pad(centers_s, ((0, pad_k), (0, 0)),
-                  constant_values=_FAR).astype(jnp.float32)
-    iv2 = jnp.pad(inv2_s, (0, pad_k), constant_values=1.0).astype(jnp.float32)
-
-    bounds = _tile_bounds(pts, cts, iv2, block_p, block_c)
+    pts, cts, iv2, bounds, order, bp, bc = _stage(points, centers,
+                                                  influence, block_p,
+                                                  block_c)
+    kw = dict(k_real=k, block_p=bp, block_c=bc, precision=precision)
     if return_moments:
         if weights is None:
             raise ValueError("return_moments=True requires weights")
-        w = jnp.pad(weights, (0, pad_n)).astype(jnp.float32)
-        idx_s, best, second, m = assign_reduce_pallas(
-            pts, cts, iv2, bounds, w, k_real=k, block_p=block_p,
-            block_c=block_c, precision=precision)
-        # un-sort the [d+2, K_pad] moment block: sorted column j belongs
-        # to original center order[j]; padded columns carry no weight
-        m_orig = jnp.zeros((k, d + 2), jnp.float32).at[order].set(m.T[:k])
-        idx_s, best, second = idx_s[:n], best[:n], second[:n]
-        idx = order[jnp.clip(idx_s, 0, k - 1)].astype(jnp.int32)
-        return (idx, best, second,
-                m_orig[:, :d], m_orig[:, d], m_orig[:, d + 1])
-    idx_s, best, second = assign_argmin_pallas(
-        pts, cts, iv2, bounds, k_real=k, block_p=block_p, block_c=block_c,
-        precision=precision)
-    idx_s, best, second = idx_s[:n], best[:n], second[:n]
-    # map sorted-center index back to the original center id
-    idx = order[jnp.clip(idx_s, 0, k - 1)].astype(jnp.int32)
-    return idx, best, second
+        w = jnp.pad(weights, (0, pts.shape[1] - n)).astype(jnp.float32)
+        idx_s, best, second, m = assign_reduce_pallas(pts, cts, iv2, bounds,
+                                                      w, **kw)
+        m = m[:, :k].T                                     # [k, d+2]
+        if order is not None:
+            # sorted column j belongs to original center order[j]
+            m = jnp.zeros_like(m).at[order].set(m)
+    else:
+        idx_s, best, second = assign_argmin_pallas(pts, cts, iv2, bounds,
+                                                   **kw)
+    idx = jnp.clip(idx_s[:n], 0, k - 1)
+    if order is not None:
+        idx = order[idx]
+    out = (idx.astype(jnp.int32), best[:n], second[:n])
+    if return_moments:
+        out += _split_moments(m, d)
+    return out
 
 
 @register_assign_backend("pallas", supports_moments=True)
 def assign_argmin_pallas_backend(points, centers, influence, *,
                                  chunk: int | None = None,
-                                 block_p: int = 1024,
+                                 block_p: int = 8192,
                                  block_c: int = 128, weights=None,
                                  return_moments: bool = False,
                                  precision: str = "f32"):
@@ -414,45 +442,31 @@ def assign_argmin_pallas_backend(points, centers, influence, *,
 
 
 def tile_prune_fraction(points, centers, influence, second_sq,
-                        block_p: int = 1024, block_c: int = 128):
+                        block_p: int = 8192, block_c: int = 128):
     """Host-side estimate of the fraction of (point-tile × center-tile)
     grid steps the Pallas kernel's ``pl.when`` bbox bound prunes, for
     ``stats["tiles_pruned_frac"]`` (useful-vs-wasted compute in the
     roofline table).
 
-    Mirrors the kernel's setup — centers sorted by bbox distance, point
-    and center axes padded to tile multiples (edge-replicated points so
-    tile bboxes stay tight) — then counts pairs whose bound cannot beat
-    the point tile's worst *converged* second-best (``second_sq``, in
-    effective-squared space, e.g. ``lb**2`` after a balance pass). The
-    first center tile is never pruned (the kernel unconditionally
-    computes j == 0 to initialize its accumulators). This is the
-    steady-state bound — inside one sweep the kernel's running
-    second-best starts at +inf, so the realized fraction converges to
-    this value from below. Traceable; psum the numerator under shard_map
-    (balanced_kmeans averages it over shards).
+    Stages the inputs as the kernel's wrapper does (``_stage``: the same
+    tiles, sort and edge-padded points), then counts pairs whose bound
+    cannot beat the point tile's worst *converged* second-best
+    (``second_sq``, in effective-squared space, e.g. ``lb**2`` after a
+    balance pass). The first center tile is never pruned (the kernel
+    unconditionally computes j == 0 to initialize its accumulators), so
+    one center tile prunes nothing. This is the steady-state bound —
+    inside one sweep the kernel's running second-best starts at +inf, so
+    the realized fraction converges to this value from below. Traceable;
+    psum the numerator under shard_map (balanced_kmeans averages it over
+    shards).
     """
-    n, d = points.shape
-    k = centers.shape[0]
-    inv2 = 1.0 / (influence * influence)
-    lo = jnp.min(points, axis=0)
-    hi = jnp.max(points, axis=0)
-    gap = jnp.maximum(jnp.maximum(lo[None] - centers, centers - hi[None]),
-                      0.0)
-    key = jnp.sum(gap * gap, axis=1) * inv2
-    order = jnp.argsort(key)
-    pad_n = (-n) % block_p
-    pad_k = (-k) % block_c
-    pts = jnp.pad(points, ((0, pad_n), (0, 0)), mode="edge")
-    cts = jnp.pad(centers[order], ((0, pad_k), (0, 0)),
-                  constant_values=_FAR)
-    iv2 = jnp.pad(inv2[order], (0, pad_k), constant_values=1.0)
-    bounds = _tile_bounds(pts.astype(jnp.float32), cts.astype(jnp.float32),
-                          iv2.astype(jnp.float32), block_p, block_c)
-    sec = jnp.pad(second_sq, (0, pad_n), mode="edge")
+    _, _, _, bounds, _, bp, _ = _stage(points, centers, influence, block_p,
+                                       block_c)
+    sec = jnp.pad(second_sq, (0, bounds.shape[0] * bp - second_sq.shape[0]),
+                  mode="edge")
     # a tile prunes only when the bound beats its WORST second-best; an
     # infinite second (k == 1) makes the tile unprunable, as in-kernel
-    worst = jnp.max(sec.reshape(-1, block_p), axis=1)     # [nPT]
+    worst = jnp.max(sec.reshape(-1, bp), axis=1)           # [nPT]
     prunable = bounds >= worst[:, None]
     prunable = prunable.at[:, 0].set(False)               # j == 0 runs
     return jnp.mean(prunable.astype(jnp.float32))

@@ -9,8 +9,8 @@ gated benchmark record (``BENCH_scaling.json`` → ``roofline``, gate
 
 Two cost terms per (n, d, k, block_p, block_c) sweep:
 
-* **distance block** — the ``[BP, BC]`` effective-distance tile per
-  (point-tile × center-tile) grid step: a ``2*BP*BC*d``-FLOP MXU matmul
+* **distance block** — the ``[BC, BP]`` effective-distance tile per
+  (point-tile × center-tile) grid step: ``2*BP*BC*d`` cross-term FLOPs
   plus an O(BP*BC) epilogue (norm adds, influence scale, running
   argmin/min/second update, modeled at ``EPILOGUE_FLOPS_PER_CELL``).
   Pruned tiles (``prune_frac``, measured by ``stats["tiles_pruned_frac"]``)
@@ -19,8 +19,7 @@ Two cost terms per (n, d, k, block_p, block_c) sweep:
   ``2*BP*(d+2)*K`` one-hot matmul per point tile.
 
 HBM traffic model: the point array streams exactly once (``4*n*d``
-logical bytes; the lane padding of a ``[n, d]`` tile is not modeled), the
-center block
+bytes, lane-dense ``[d, n]``), the center block
 (``4*(d+1)*K``) is re-fetched per point tile, outputs are
 ``12*n`` bytes (idx/best/second) plus the ``4*(d+2)*K`` moment block.
 ``precision="bf16"`` halves the *MXU time* of the distance matmul

@@ -37,6 +37,19 @@ def _rand(n, k, d, seed=0, spread=1.0):
     (2048, 300, 2, 1024, 256),
     (2048, 300, 3, 1024, 256),
     (1024, 300, 128, 1024, 256),
+    # lane-dense tiles: k not a multiple of 8 (padded center rows), k = 64
+    # in one derived 64-row center tile, point tiles above 1024; d = 2, 3
+    # take the VPU cross term, d = 16, 128 the MXU one
+    (3000, 9, 2, 2048, 128),
+    (3000, 9, 16, 2048, 128),
+    (2500, 33, 3, 4096, 128),
+    (2500, 33, 128, 2048, 128),
+    (1500, 1, 2, 2048, 128),
+    (1500, 1, 16, 2048, 128),
+    (5000, 64, 2, 2048, 128),
+    (5000, 64, 3, 8192, 128),
+    (3000, 64, 16, 2048, 128),
+    (2000, 64, 128, 2048, 128),
 ])
 def test_kernel_matches_ref(n, k, d, bp, bc):
     pts, ctr, infl = _rand(n, k, d)
@@ -176,6 +189,12 @@ def test_jnp_fused_bitexact_vs_unfused(n, chunk):
     (1024, 200, 3, 256, 128),
     (512, 200, 128, 256, 128),
     (2048, 300, 2, 1024, 256),
+    # lane-dense tiles (see test_kernel_matches_ref)
+    (3000, 9, 3, 2048, 128),
+    (2500, 33, 2, 4096, 128),
+    (1500, 1, 16, 2048, 128),
+    (5000, 64, 2, 2048, 128),
+    (3000, 64, 128, 2048, 128),
 ])
 def test_kernel_fused_moments_match_jnp(backend, n, k, d, bp, bc):
     """Fused==unfused parity per kernel backend: the VMEM-accumulated
@@ -239,17 +258,18 @@ def test_nonmultiple_n_at_kernel_entry_raises():
     pts, ctr, infl = _rand(1000, 8, 2, seed=23)   # 1000 % 256 != 0
     inv2 = 1.0 / (infl * infl)
     bounds = jnp.zeros((4, 1), jnp.float32)
+    # the TPU kernel takes lane-dense [d, n] points, the triton one [n, d]
     with pytest.raises(ValueError, match=r"n=1000.*block_p=256"):
-        assign_argmin_pallas(pts, ctr, inv2, bounds, k_real=8,
+        assign_argmin_pallas(pts.T, ctr, inv2, bounds, k_real=8,
                              block_p=256, block_c=8)
     with pytest.raises(ValueError, match=r"n=1000.*block_p=256"):
-        assign_reduce_pallas(pts, ctr, inv2, bounds, jnp.ones(1000),
+        assign_reduce_pallas(pts.T, ctr, inv2, bounds, jnp.ones(1000),
                              k_real=8, block_p=256, block_c=8)
     with pytest.raises(ValueError, match=r"n=1000.*block_p=256"):
         triton_assign_pallas(pts, ctr, inv2, k_real=8,
                              block_p=256, block_c=8)
     with pytest.raises(ValueError, match=r"k=8.*block_c=128"):
-        assign_argmin_pallas(pts[:768], ctr, inv2, bounds, k_real=8,
+        assign_argmin_pallas(pts[:768].T, ctr, inv2, bounds, k_real=8,
                              block_p=256, block_c=128)
 
 

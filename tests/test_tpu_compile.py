@@ -54,10 +54,10 @@ def _spec(sharding, shape, dtype=jnp.float32):
 
 
 def _kernel_args(sharding, d, k):
-    """Shapes of one kernel call: points, centers padded to a block_c
-    multiple, inverse influence, per-tile bounds, weights."""
+    """Shapes of one kernel call: lane-dense [d, N] points, centers padded
+    to a block_c multiple, inverse influence, per-tile bounds, weights."""
     kpad = -(-k // BLOCK_C) * BLOCK_C
-    return (_spec(sharding, (N, d)), _spec(sharding, (kpad, d)),
+    return (_spec(sharding, (d, N)), _spec(sharding, (kpad, d)),
             _spec(sharding, (kpad,)),
             _spec(sharding, (N // BLOCK_P, kpad // BLOCK_C)),
             _spec(sharding, (N,)))
@@ -115,19 +115,34 @@ def test_batched_solve_compiles(one_chip, compiled_kernels):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def test_cold_solve_keeps_its_kernel_name_and_scopes(one_chip,
-                                                     compiled_kernels):
-    """The cold solve at an odd n, so that every sweep pads its points to
-    a whole tile: the assign kernel keeps the short HLO name the
-    benchmark's roofline reader keys on, and the op metadata carries the
-    solve's named scopes, by which a profiler groups the chip's ops."""
-    from chipbench.tracefile import short_name
+COLD_N, COLD_K = (1 << 16) + 42, 64
+
+
+@pytest.fixture(scope="module")
+def cold_solve_text(one_chip):
+    """HLO of the cold solve compiled at an odd n, so that every sweep pads
+    its points to a whole tile, with the kernel compiled as on a TPU host
+    (traces made under the patch are dropped afterwards)."""
+    import repro.kernels.assign_kernel as assign_kernel
     from repro.core.balanced_kmeans import BKMConfig
     from repro.core.partitioner import _run_jit
-    n, k = (1 << 16) + 42, 64
-    cfg = BKMConfig(k=k, backend="pallas")
-    text = _run_jit.lower(_spec(one_chip, (n, 2)), cfg, None,
-                          _spec(one_chip, (k, 2))).compile().as_text()
+    jax.clear_caches()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(assign_kernel, "default_interpret", lambda: False)
+        cfg = BKMConfig(k=COLD_K, backend="pallas")
+        text = _run_jit.lower(_spec(one_chip, (COLD_N, 2)), cfg, None,
+                              _spec(one_chip, (COLD_K, 2))
+                              ).compile().as_text()
+    jax.clear_caches()
+    return text
+
+
+def test_cold_solve_keeps_its_kernel_name_and_scopes(cold_solve_text):
+    """The assign kernel keeps the short HLO name the benchmark's roofline
+    reader keys on, and the op metadata carries the solve's named scopes,
+    by which a profiler groups the chip's ops."""
+    from chipbench.tracefile import short_name
+    text = cold_solve_text
     names = {short_name(line.strip().removeprefix("ROOT "))
              for line in text.splitlines()
              if line.strip().removeprefix("ROOT ").startswith("%")}
@@ -135,3 +150,22 @@ def test_cold_solve_keeps_its_kernel_name_and_scopes(one_chip,
     assert "pad" in names
     for scope in ("movement", "balance", "final_pass"):
         assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
+
+
+def test_cold_solve_kernel_takes_lane_dense_points(cold_solve_text):
+    """Every kernel call of the compiled cold solve takes its points as
+    one lane-dense ``f32[2, n_pad]`` operand (coordinates on sublanes,
+    points on the 128 lanes), n padded to the kernel's point tile."""
+    from repro.core.balanced_kmeans import BKMConfig
+    from repro.kernels.ops import kernel_tiles
+    cfg = BKMConfig(k=COLD_K)
+    bp, _ = kernel_tiles(COLD_N, COLD_K, cfg.block_p, cfg.block_c)
+    n_pad = -(-COLD_N // bp) * bp
+    calls = re.findall(r"%assign_reduce_pallas[.\d]* = .*?"
+                       r"operand_layout_constraints=\{(.*?\})\}",
+                       cold_solve_text)
+    assert calls
+    for operands in calls:
+        # operands: prune bounds, points, centers, inv2, weights
+        points = re.findall(r"\w+\[[\d,]*\]", operands)[1]
+        assert points == f"f32[2,{n_pad}]", points
