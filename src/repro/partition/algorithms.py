@@ -61,17 +61,29 @@ def _geographer(problem: PartitionProblem,
         raise TypeError("chunk= streams the sharded deal and only applies "
                         "to the multi-device path (pass devices=)")
     cfg = make_bkm_config(problem, **opts)
-    labels, centers, infl, stats = geographer_partition(
+    return _geographer_result(problem, geographer_partition(
         problem.points, problem.k, weights=problem.weights, cfg=cfg,
-        seed=problem.seed, return_state=True)
+        seed=problem.seed, return_state=True))
+
+
+def _geographer_result(problem: PartitionProblem, solved, *,
+                       warm: bool = False, **extra) -> PartitionResult:
+    """The ``PartitionResult`` of every balanced-k-means route. ``solved``
+    is what a layout's ``_stage_solve_fetch`` returns (labels, centers,
+    influence, stats); a ``warm`` solve adds its ``iters`` and
+    ``warm_start``, and ``extra`` further stats keys (the sharded routes'
+    ``devices`` and ``bootstrap``)."""
+    labels, centers, infl, stats = solved
+    summary = {"levels": [dict(stats)],
+               "final_imbalance": float(stats["final_imbalance"])}
+    if warm:
+        summary.update(iters=int(stats["iters"]), warm_start=True)
     # centers/influence ride on the result so repartition() can warm-start
     # the next solve from this one (DESIGN.md §8)
     return PartitionResult(
         labels=np.asarray(labels, np.int64), k=problem.k,
-        method="geographer", problem=problem,
-        centers=centers, influence=infl,
-        stats={"levels": [dict(stats)],
-               "final_imbalance": float(stats["final_imbalance"])})
+        method="geographer", problem=problem, centers=centers,
+        influence=infl, stats={**summary, **extra})
 
 
 def _baseline_result(problem, labels, method) -> PartitionResult:

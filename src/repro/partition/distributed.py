@@ -20,6 +20,10 @@ bounding box [d]. This module is the actual SPMD driver for that claim:
   ``core.balanced_kmeans`` under ``shard_map`` with ``axis_name`` plumbed
   end-to-end, so every ``_reduce`` in the core becomes a ``psum`` /
   ``pmin`` / ``pmax`` — the paper's communication structure, nothing else.
+* ``repartition_sharded`` — the warm-started twin of ``partition_sharded``
+  (the ``devices=`` path of ``repartition()``): the previous centers and
+  influence are replicated like any centers, so warm starting adds no
+  collective.
 * ``devices=(P1, P2)`` — the same solve on the 2-D hierarchical mesh
   (``dist.rules.partition_mesh2d``): points shard over the *product* of
   the ``("coarse", "refine")`` axes, every reduction psums over the axis
@@ -74,6 +78,7 @@ from repro.dist.rules import (COARSE_AXIS, PARTITION_AXIS, REFINE_AXIS,
                               partition_mesh, partition_mesh2d, shard_map)
 from repro.kernels.ops import backend_supports_moments, resolve_assign_backend
 
+from .algorithms import _geographer_result, make_bkm_config
 from .problem import PartitionProblem, PartitionResult
 
 BOOTSTRAPS = ("host", "device")
@@ -455,134 +460,43 @@ def _prep_sharded_cfg(problem: PartitionProblem, devices,
     return sp, cfg
 
 
-def geographer_partition_sharded(problem: PartitionProblem, devices,
-                                 cfg: BKMConfig | None = None,
-                                 bootstrap: str = "host",
-                                 chunk: int | None = None):
-    """Raw sharded (cold-start) run.
-
-    Args:
-        problem: the partitioning instance; its seed fixes the round-robin
-            deal permutation.
-        devices: number of shards P (1 <= P <= problem.n), or a (P1, P2)
-            2-D mesh shape — bit-identical to the flat P1*P2 run.
-        cfg: BKMConfig; None uses the problem's (k, epsilon) defaults.
-        bootstrap: "host" (host-side SFC centers, identical to the
-            single-device path) or "device" (in-graph distributed SFC
-            bootstrap — also the out-of-core choice: no O(n) float64
-            host copy of the points).
-        chunk: per-shard slots per deal slice (streaming deal; None =
-            one shot — results are bit-identical either way).
+def _stage_solve_fetch(problem: PartitionProblem, devices, cfg: BKMConfig,
+                       mode: str, centers0, influence0=None,
+                       prev_labels=None, chunk: int | None = None, *,
+                       attempt: int | None = None, pad: bool = False):
+    """The sharded layout's one stage -> solve -> fetch driver: the deal
+    (``_prep_sharded_cfg``), one run of the ``mode`` runner ("host",
+    "device" or "warm"), and ``scatter_labels``. A warm solve records
+    ``attempt`` on its ``repro.solve`` span; ``pad`` rounds its
+    per-shard ``cap`` up by ``core.partitioner.warm_slots`` so that
+    point counts of one bucket share one compiled runner.
 
     Returns:
-        (labels [n] int64 in original point order, centers [k, d],
-        influence [k], stats dict) — prefer the front door
-        ``partition(problem, devices=...)``.
+        (labels [n] int64, centers [k, d], influence [k], stats dict).
     """
-    if bootstrap not in BOOTSTRAPS:
-        raise ValueError(f"bootstrap must be one of {BOOTSTRAPS}, "
-                         f"got {bootstrap!r}")
-    cfg = cfg or BKMConfig(k=problem.k, epsilon=problem.epsilon)
-    if bootstrap == "host":
-        with jax.profiler.TraceAnnotation("repro.bootstrap"):
-            centers0 = sfc_initial_centers(
-                np.asarray(problem.points, np.float64), cfg.k,
-                problem.weights)
-    else:
-        centers0 = np.zeros((cfg.k, problem.dim))      # ignored in-graph
-    with jax.profiler.TraceAnnotation("repro.stage"):
-        sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk)
-        run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
-                            bootstrap, problem.n)
-        args = jax.block_until_ready((
-            sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype),
-            jnp.ones(cfg.k, cfg.dtype),
-            jnp.zeros(sp.devices * sp.cap, jnp.int32),
-            jnp.int32(problem.n)))
-    with jax.profiler.TraceAnnotation("repro.solve",
-                                      slots=sp.devices * sp.cap):
-        solved = jax.block_until_ready(run(*args))
-    with jax.profiler.TraceAnnotation("repro.fetch"):
-        A, centers, infl, stats = jax.device_get(solved)
-        labels = sp.scatter_labels(A, chunk=chunk)
-    return labels, centers, infl, stats
-
-
-def geographer_repartition_sharded(problem: PartitionProblem, devices,
-                                   centers0: np.ndarray,
-                                   influence0: np.ndarray | None = None,
-                                   cfg: BKMConfig | None = None,
-                                   prev_labels: np.ndarray | None = None,
-                                   chunk: int | None = None,
-                                   *, attempt: int = 0, pad: bool = False):
-    """Raw sharded warm-start run: balanced k-means resumed from a previous
-    partition's (centers0, influence0) state, no SFC bootstrap.
-
-    The previous centers and influence are replicated across shards
-    (exactly like every cold run's centers) and the communication pattern
-    stays psum-only — warm starting adds zero new collectives. The shard
-    layout comes from the problem's seed, so ``devices=1`` is bit-for-bit
-    identical to ``core.partitioner.geographer_repartition`` with the same
-    seed.
-
-    Args:
-        problem: the (possibly re-weighted / moved) partitioning instance.
-        devices: number of shards P, or a (P1, P2) 2-D mesh shape.
-        centers0: [k, d] previous centers.
-        influence0: [k] previous influence (None = ones).
-        cfg: BKMConfig; ``warmup`` is forced off.
-        prev_labels: [n] previous block ids in original point order; when
-            given, an unchanged-and-still-balanced partition is re-emitted
-            verbatim (no-op detection). Padded slots replicate real
-            points, so the comparison is consistent across the deal.
-            ``repartition()`` always passes the previous labels; when a
-            direct caller omits them, a -1 sentinel is dealt instead —
-            it can never equal a real assignment (labels are >= 0), so
-            no-op detection and migration-style comparisons can never
-            fire on synthetic labels (locked by
-            tests/test_out_of_core.py).
-        chunk: per-shard slots per deal slice (None = one shot).
-        attempt: which solve of the caller's balance-retry loop this is;
-            recorded on the ``repro.solve`` trace span.
-        pad: round the per-shard ``cap`` up by
-            ``core.partitioner.warm_slots``, as the flat path pads its
-            point count, so that point counts of one bucket share one
-            compiled runner.
-
-    Returns:
-        (labels [n] int64, centers [k, d], influence [k], stats dict);
-        ``stats["iters"]`` is 0 when the previous state is still a fixed
-        point. Prefer ``repartition(problem, previous, devices=...)``.
-    """
-    cfg = cfg or BKMConfig(k=problem.k, epsilon=problem.epsilon,
-                           warmup=False)
-    if cfg.warmup:
-        cfg = dataclasses.replace(cfg, warmup=False)
-    if centers0.shape[0] != cfg.k:
-        raise ValueError(f"centers0 has {centers0.shape[0]} rows, "
-                         f"k={cfg.k}")
+    warm = mode == "warm"
     with jax.profiler.TraceAnnotation("repro.stage"):
         cap = (warm_slots(check_index_capacity(problem.n, devices)) if pad
                else None)
         sp, cfg = _prep_sharded_cfg(problem, devices, cfg, chunk=chunk,
                                     cap=cap)
         run = _build_runner(_runner_key(devices), sp.cap, problem.dim, cfg,
-                            "warm", None)
-        infl0 = (jnp.ones(cfg.k, cfg.dtype) if influence0 is None
-                 else jnp.asarray(influence0, cfg.dtype))
-        if prev_labels is None:
-            # synthetic sentinel: -1 never matches a real assignment
-            # (block ids are >= 0), so the no-op shortcut in the core
-            # cannot fire on a partition that never existed — the solver
-            # always re-assigns from (centers0, influence0)
-            prev = np.full((sp.devices, sp.cap), -1, np.int32)
-        else:
-            prev = sp.deal(np.asarray(prev_labels, np.int32), chunk=chunk)
+                            mode, None if warm else problem.n)
+        # prev_labels=None deals a -1 sentinel: it never matches a real
+        # assignment (block ids are >= 0), so the core's no-op shortcut
+        # cannot fire on a partition that never existed (a cold runner
+        # never reads it)
+        prev = (jnp.full(sp.devices * sp.cap, -1, jnp.int32)
+                if prev_labels is None else jnp.asarray(
+                    sp.deal(np.asarray(prev_labels, np.int32),
+                            chunk=chunk).reshape(-1), jnp.int32))
         args = jax.block_until_ready((
-            sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype), infl0,
-            jnp.asarray(prev.reshape(-1), jnp.int32),
-            jnp.int32(problem.n)))
-    with jax.profiler.TraceAnnotation("repro.solve", attempt=attempt,
+            sp.points, sp.weights, jnp.asarray(centers0, cfg.dtype),
+            jnp.ones(cfg.k, cfg.dtype) if influence0 is None
+            else jnp.asarray(influence0, cfg.dtype),
+            prev, jnp.int32(problem.n)))
+    span = {"attempt": attempt} if warm else {}
+    with jax.profiler.TraceAnnotation("repro.solve", **span,
                                       slots=sp.devices * sp.cap):
         solved = jax.block_until_ready(run(*args))
     with jax.profiler.TraceAnnotation("repro.fetch"):
@@ -620,16 +534,22 @@ def partition_sharded(problem: PartitionProblem, devices, *,
         start — and ``stats`` carrying the k-means iteration history plus
         ``devices`` / ``bootstrap``.
     """
-    from .algorithms import make_bkm_config
     cfg = make_bkm_config(problem, **opts)
-    labels, centers, infl, stats = geographer_partition_sharded(
-        problem, devices, cfg=cfg, bootstrap=bootstrap, chunk=chunk)
-    return PartitionResult(
-        labels=labels, k=problem.k, method="geographer", problem=problem,
-        centers=centers, influence=infl,
-        stats={"levels": [dict(stats)],
-               "final_imbalance": float(stats["final_imbalance"]),
-               "devices": _devices_stat(devices), "bootstrap": bootstrap})
+    if bootstrap not in BOOTSTRAPS:
+        raise ValueError(f"bootstrap must be one of {BOOTSTRAPS}, "
+                         f"got {bootstrap!r}")
+    if bootstrap == "host":
+        with jax.profiler.TraceAnnotation("repro.bootstrap"):
+            centers0 = sfc_initial_centers(
+                np.asarray(problem.points, np.float64), cfg.k,
+                problem.weights)
+    else:
+        centers0 = np.zeros((cfg.k, problem.dim))      # ignored in-graph
+    solved = _stage_solve_fetch(problem, devices, cfg, bootstrap, centers0,
+                                chunk=chunk)
+    return _geographer_result(problem, solved,
+                              devices=_devices_stat(devices),
+                              bootstrap=bootstrap)
 
 
 def repartition_sharded(problem: PartitionProblem, devices,
@@ -640,6 +560,11 @@ def repartition_sharded(problem: PartitionProblem, devices,
                         pad: bool = False, **opts) -> PartitionResult:
     """Multi-device warm-started repartition (the ``devices=`` path of the
     ``repartition()`` front door).
+
+    The previous centers and influence are replicated across shards like
+    any cold run's centers, so warm starting adds no collective; the
+    layout comes from the problem's seed, so ``devices=1`` is bit-for-bit
+    ``core.partitioner.geographer_repartition`` with the same seed.
 
     Args:
         problem: the perturbed partitioning instance.
@@ -652,8 +577,9 @@ def repartition_sharded(problem: PartitionProblem, devices,
         chunk: per-shard slots per deal slice (None = one shot).
         attempt: the balance-retry attempt, recorded on the
             ``repro.solve`` trace span.
-        pad: pad the shards as ``geographer_repartition_sharded`` says
-            (a point set that changes from step to step).
+        pad: round the per-shard ``cap`` up by
+            ``core.partitioner.warm_slots`` (a point set that changes
+            from step to step), as the flat path pads its point count.
         **opts: BKMConfig field overrides (``warmup`` is forced off).
 
     Returns:
@@ -661,15 +587,12 @@ def repartition_sharded(problem: PartitionProblem, devices,
         ``stats["warm_start"] = True`` and the movement iteration count at
         ``stats["iters"]``).
     """
-    from .algorithms import make_bkm_config
     cfg = make_bkm_config(problem, **dict(opts, warmup=False))
-    labels, centers, infl, stats = geographer_repartition_sharded(
-        problem, devices, centers0, influence0, cfg=cfg,
-        prev_labels=prev_labels, chunk=chunk, attempt=attempt, pad=pad)
-    return PartitionResult(
-        labels=labels, k=problem.k, method="geographer", problem=problem,
-        centers=centers, influence=infl,
-        stats={"levels": [dict(stats)],
-               "final_imbalance": float(stats["final_imbalance"]),
-               "iters": int(stats["iters"]),
-               "devices": _devices_stat(devices), "warm_start": True})
+    if centers0.shape[0] != cfg.k:
+        raise ValueError(f"centers0 has {centers0.shape[0]} rows, "
+                         f"k={cfg.k}")
+    solved = _stage_solve_fetch(problem, devices, cfg, "warm", centers0,
+                                influence0, prev_labels, chunk,
+                                attempt=attempt, pad=pad)
+    return _geographer_result(problem, solved, warm=True,
+                              devices=_devices_stat(devices))

@@ -40,6 +40,8 @@ import numpy as np
 from repro.core import metrics
 from repro.core.partitioner import geographer_repartition
 
+from .algorithms import _geographer_result, make_bkm_config
+from .distributed import repartition_sharded
 from .engine import _CALLS, partition
 from .problem import PartitionProblem, PartitionResult
 from .registry import resolve_method, supports_warm_start
@@ -262,43 +264,37 @@ def _warm_geographer(problem: PartitionProblem, previous: PartitionResult,
     resumes from the previous centers and influence alone (they are per
     block and carry over), without no-op detection, over a point count
     padded to its ``warm_slots`` bucket."""
-    from .algorithms import make_bkm_config
-    from .distributed import repartition_sharded
     opts.setdefault("delta_tol", WARM_DELTA_TOL)
     opts["warmup"] = False
     state = WarmState.capture(previous)
     centers, infl = state.centers, state.influence
     prev_labels = None if changed else state.labels
+    if devices is None:
+        cfg = make_bkm_config(problem, **opts)
+
+        def solve(centers, infl, prev_labels, attempt):
+            return _geographer_result(problem, geographer_repartition(
+                problem.points, problem.k, centers, infl,
+                weights=problem.weights, cfg=cfg, seed=problem.seed,
+                prev_labels=prev_labels, attempt=attempt, pad=changed),
+                warm=True)
+    else:
+        def solve(centers, infl, prev_labels, attempt):
+            return repartition_sharded(problem, devices, centers, infl,
+                                       prev_labels=prev_labels,
+                                       attempt=attempt, pad=changed, **opts)
     # the solver balances against the caller's effective epsilon (an
     # opts override wins over the problem's), so the retry check must too
     eps_eff = opts.get("epsilon", problem.epsilon)
     total_iters = 0
     for attempt in range(MAX_BALANCE_RETRIES + 1):
-        if devices is not None:
-            res = repartition_sharded(problem, devices, centers, infl,
-                                      prev_labels=prev_labels,
-                                      attempt=attempt, pad=changed, **opts)
-            iters = res.stats["iters"]
-            imb = res.stats["final_imbalance"]
-            centers, infl = res.centers, res.influence
-            labels = res.labels
-        else:
-            cfg = make_bkm_config(problem, **opts)
-            labels, centers, infl, stats = geographer_repartition(
-                problem.points, problem.k, centers, infl,
-                weights=problem.weights, cfg=cfg, seed=problem.seed,
-                prev_labels=prev_labels, attempt=attempt, pad=changed)
-            iters = int(stats["iters"])
-            imb = float(stats["final_imbalance"])
-            res = PartitionResult(
-                labels=labels, k=problem.k, method="geographer",
-                problem=problem, centers=centers, influence=infl,
-                stats={"levels": [dict(stats)], "final_imbalance": imb})
-        total_iters += iters
-        if imb <= eps_eff + 1e-6:
+        res = solve(centers, infl, prev_labels, attempt)
+        total_iters += res.stats["iters"]
+        centers, infl = res.centers, res.influence
+        if res.stats["final_imbalance"] <= eps_eff + 1e-6:
             break
         if not changed:
-            prev_labels = np.asarray(labels)
+            prev_labels = res.labels
     res.stats.update({"warm_start": True, "iters": total_iters,
                       "balance_retries": attempt})   # re-warm solves run
     return res

@@ -24,8 +24,8 @@ import pytest
 from repro.partition import PartitionProblem, ShardedPartitionProblem
 from repro.partition.distributed import (INT32_INDEX_CAP,
                                          check_index_capacity,
-                                         geographer_repartition_sharded,
-                                         partition_sharded)
+                                         partition_sharded,
+                                         repartition_sharded)
 
 needs8 = pytest.mark.skipif(len(jax.devices()) < 8,
                             reason="needs 8 (virtual) jax devices")
@@ -165,12 +165,11 @@ class TestWarmSentinel:
         # and pull the center onto the weighted centroid.
         prob = _problem(k=1)
         centers0 = np.array([[10.0, 10.0]])
-        labels, centers, _, stats = geographer_repartition_sharded(
-            prob, 2, centers0)
-        assert int(stats["iters"]) >= 1
-        assert np.array_equal(labels, np.zeros(prob.n, np.int64))
+        res = repartition_sharded(prob, 2, centers0)
+        assert int(res.stats["iters"]) >= 1
+        assert np.array_equal(res.labels, np.zeros(prob.n, np.int64))
         centroid = np.average(prob.points, axis=0, weights=prob.weights)
-        assert np.allclose(np.asarray(centers)[0], centroid, atol=1e-3)
+        assert np.allclose(np.asarray(res.centers)[0], centroid, atol=1e-3)
 
     def test_real_prev_labels_still_noop(self):
         # the counterpart: a genuine fixed point re-submitted WITH its
@@ -178,16 +177,15 @@ class TestWarmSentinel:
         prob = _problem(k=1)
         centroid = np.average(prob.points, axis=0, weights=prob.weights)
         prev = np.zeros(prob.n, np.int64)
-        labels, _, _, stats = geographer_repartition_sharded(
-            prob, 2, centroid[None, :], prev_labels=prev)
-        assert int(stats["iters"]) == 0
-        assert np.array_equal(labels, prev)
+        res = repartition_sharded(prob, 2, centroid[None, :],
+                                  prev_labels=prev)
+        assert int(res.stats["iters"]) == 0
+        assert np.array_equal(res.labels, prev)
 
     def test_sentinel_chunked_matches_oneshot(self):
         prob = _problem(k=4)
         centers0 = prob.points[:4].astype(np.float64)
-        la, ca, _, _ = geographer_repartition_sharded(prob, 2, centers0)
-        lb, cb, _, _ = geographer_repartition_sharded(prob, 2, centers0,
-                                                      chunk=19)
-        assert np.array_equal(la, lb)
-        assert np.array_equal(np.asarray(ca), np.asarray(cb))
+        a = repartition_sharded(prob, 2, centers0)
+        b = repartition_sharded(prob, 2, centers0, chunk=19)
+        assert np.array_equal(a.labels, b.labels)
+        assert np.array_equal(np.asarray(a.centers), np.asarray(b.centers))

@@ -4,8 +4,8 @@ Waiver entries silence one rule at one site::
 
     [[waiver]]
     rule = "SPMD001"
-    path = "src/repro/core/partitioner.py"
-    symbol = "_sfc_redistribute"        # optional; omit = whole file
+    path = "src/repro/pkg/module.py"
+    symbol = "exchange"                 # optional; omit = whole file
     reason = "why this site is sanctioned"
 
 ``path`` matches by normalized suffix, so waivers keep working whether
